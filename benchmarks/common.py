@@ -16,6 +16,10 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
 ROWS: list[tuple] = []
 
 

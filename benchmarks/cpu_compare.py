@@ -14,7 +14,7 @@ from repro.core.gemm.cmr import TPU_V5E, TpuSpec
 
 CPU_SPEC = TpuSpec(name="ft2000plus_cpu", peak_flops_bf16=281.6e9,
                    peak_flops_fp32=281.6e9, hbm_bw=42.6e9,
-                   vmem_budget=32 * 1024 * 1024,   # L2-ish blocking budget
+                   vmem_limit=64 * 1024 * 1024,    # L2-ish blocking budget x2
                    lane=4, sublane_fp32=4, mxu=4)
 
 from .common import record

@@ -117,13 +117,32 @@ def _check_dim(out: list[Violation], name: str, blk: int, extent: int,
             f"{name}-fold (the PR 5 bk-clamp bug class)"))
 
 
+def bm_unit(trans: str, in_bytes: int, spec: Any = None) -> int:
+    """Alignment unit of ``bm``: the sublane tile where M is the row axis of
+    A's ``(bm, bk)`` block, the lane tile where it is the minor axis of the
+    transposed ``(bk, bm)`` block (``trans == "tn"``)."""
+    sp = _spec(spec)
+    return sp.lane if trans == "tn" else sp.sublane(in_bytes)
+
+
+def _check_bm(out: list[Violation], bm: int, m: int, trans: str,
+              in_bytes: int, spec: Any) -> None:
+    _check_dim(out, "bm", bm, m, bm_unit(trans, in_bytes, spec),
+               "lane" if trans == "tn" else "sublane")
+
+
 def check_blocks(family: str, dims: Sequence[int], *, bm: int, bn: int,
                  bk: int, nsplit: int = 1, dim_order: str = "mn",
                  edge: str = "masked", in_bytes: int = 4, out_bytes: int = 4,
-                 ragged: str = "m", spec: Any = None) -> list[Violation]:
+                 ragged: str = "m", trans: str = "nn",
+                 spec: Any = None) -> list[Violation]:
     """Pure-geometry plan invariants: positivity, alignment per dtype,
     clamping to problem extents, split-K factor sanity.  Cheap enough to run
-    on every candidate the tuner generates, before CMR pricing."""
+    on every candidate the tuner generates, before CMR pricing.
+
+    ``bm`` follows the lane rule wherever M is the minor axis of the A
+    block: ``trans == "tn"`` (dense and batched, A block ``(bk, bm)``) and
+    the ragged dW (``ragged == "k"``, x block ``(bk, bm)``)."""
     sp = _spec(spec)
     v: list[Violation] = []
     if min(bm, bn, bk) <= 0 or nsplit <= 0:
@@ -143,7 +162,7 @@ def check_blocks(family: str, dims: Sequence[int], *, bm: int, bn: int,
         if dim_order not in _ORDERS:
             v.append(Violation("bad_dim_order",
                                f"dim_order={dim_order!r} not in {_ORDERS}"))
-        _check_dim(v, "bm", bm, m, sub, "sublane")
+        _check_bm(v, bm, m, trans, in_bytes, sp)
         _check_dim(v, "bn", bn, n, lane, "lane")
         _check_dim(v, "bk", bk, k, lane, "lane")
         if nsplit > 1 and nsplit > _cdiv(_ceil_to(max(k, 1), lane), bk):
@@ -162,7 +181,7 @@ def check_blocks(family: str, dims: Sequence[int], *, bm: int, bn: int,
         if dim_order not in _ORDERS:
             v.append(Violation("bad_dim_order",
                                f"dim_order={dim_order!r} not in {_ORDERS}"))
-        _check_dim(v, "bm", bm, m, sub, "sublane")
+        _check_bm(v, bm, m, trans, in_bytes, sp)
         _check_dim(v, "bn", bn, n, lane, "lane")
         _check_dim(v, "bk", bk, k, lane, "lane")
         if nsplit != 1:
@@ -190,9 +209,10 @@ def check_blocks(family: str, dims: Sequence[int], *, bm: int, bn: int,
             _check_dim(v, "bn", bn, n, lane, "lane")
         elif ragged == "k":
             # dW layout: bk tiles the ragged token (contraction) axis, bm
-            # tiles the D rows of the (g, D, F) output.
+            # tiles the D rows of the (g, D, F) output — D is the minor axis
+            # of the (bk, bm) x block, so bm is lane-aligned.
             _check_dim(v, "bk", bk, total, sub, "sublane")
-            _check_dim(v, "bm", bm, k, sub, "sublane")
+            _check_dim(v, "bm", bm, k, lane, "lane")
             _check_dim(v, "bn", bn, n, lane, "lane")
         else:
             v.append(Violation("bad_ragged_axis",
@@ -616,7 +636,7 @@ def check_plan(family: str, dims: Sequence[int], plan: Any, *,
                           dim_order=getattr(plan, "dim_order", "mn"),
                           edge=getattr(plan, "edge", "masked"),
                           in_bytes=in_bytes, out_bytes=out_bytes,
-                          ragged=ragged, spec=sp)
+                          ragged=ragged, trans=trans, spec=sp)
         base = vmem_footprint(family, bm=int(plan.bm), bn=int(plan.bn),
                               bk=int(plan.bk), in_bytes=in_bytes,
                               out_bytes=out_bytes, nsplit=nsplit,
@@ -633,15 +653,13 @@ def check_plan(family: str, dims: Sequence[int], plan: Any, *,
                                   ragged=ragged, epilogue=epilogue,
                                   swiglu=swiglu, b_bytes=b_bytes)
             if full > sp.vmem_budget:
-                # The tuner admits candidates on the base formula (matching
-                # cmr.estimate); extra epilogue/swiglu inputs pushing past
-                # the budget is a pricing gap, reported but not fatal.
+                # The planner admits tilings on this full footprint; a plan
+                # whose epilogue/swiglu inputs push it past the budget is
+                # one the compiler's VMEM limit may refuse.
                 v.append(Violation(
                     "vmem_budget_extras",
                     f"working set {full} B incl. epilogue/swiglu inputs "
-                    f"exceeds the {sp.vmem_budget} B budget (base {base} B "
-                    "fits — the CMR formula under-prices the extras)",
-                    severity="warning"))
+                    f"exceeds the {sp.vmem_budget} B budget (base {base} B)"))
         v += check_schedule(nsplit=nsplit, fuse=getattr(plan, "fuse", True),
                             epilogue=epilogue, swiglu=swiglu)
         if getattr(plan, "edge", "masked") == "padded":

@@ -23,8 +23,9 @@ families), and checks each candidate against the static kernel contracts
   * pruning round-trip: enabling the generators' contract pre-check changes
     no argmin plan (``verify=True`` vs ``verify=False``).
 
-Exit code 1 on any error-severity violation; warnings (e.g. the CMR
-formula's un-priced swiglu VMEM extras) are reported but never fatal.
+Exit code 1 on any error-severity violation; warnings (e.g. a K-parallel
+placement that leaves a shard without a full 128-wide panel) are reported
+but never fatal.
 """
 from __future__ import annotations
 

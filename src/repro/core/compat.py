@@ -1,134 +1,56 @@
-"""JAX version compatibility layer.
+"""The few JAX surfaces the repo wraps, for the installed jax (0.9.0).
 
-Policy: the repo targets the *pinned* container JAX (0.4.x line) while
-staying forward-compatible with newer releases.  Every API that moved or was
-renamed between 0.4.x and 0.5+/0.6+ is wrapped HERE, once, and the rest of
-the codebase imports from ``repro.core.compat`` — never version-checks
-inline.  Wrapped surfaces:
-
-  * ``shard_map``        — ``jax.shard_map`` (new) vs
-                           ``jax.experimental.shard_map.shard_map`` (0.4.x).
-  * ``shard_map_unchecked`` — shard_map with replication checking off under
-                           either kwarg name (``check_rep`` -> ``check_vma``);
-                           required around pallas_call bodies on 0.4.x.
-  * ``make_mesh``        — ``jax.make_mesh`` grew an ``axis_types`` kwarg and
-                           ``jax.sharding.AxisType`` only exists on newer
-                           releases; we always want plain Auto axes.
-  * ``normalize_cost_analysis`` — ``Compiled.cost_analysis()`` returns a
-                           list-of-dict on 0.4.x and a flat dict on newer
-                           versions.
-  * ``pallas_compiler_params`` — ``pltpu.CompilerParams`` is the new name of
-                           ``pltpu.TPUCompilerParams``.
-  * ``prefetch_scalar_grid_spec`` — ``pltpu.PrefetchScalarGridSpec`` (scalar-
-                           prefetch grids for data-dependent index maps, e.g.
-                           the ragged grouped GEMM metadata).
-  * ``ragged_all_to_all``  — ``jax.lax.ragged_all_to_all`` exists only on
-                           newer releases (and not on every backend); exposed
-                           as ``None`` when absent so the collective exchange
-                           layer can probe for it and fall back to the dense
-                           realization.
+  * ``shard_map``           — ``jax.shard_map``.
+  * ``shard_map_unchecked`` — ``shard_map`` with ``check_vma=False``: the
+                              ftIMM ``pallas_call`` bodies carry no
+                              varying-manual-axes annotations.
+  * ``make_mesh``           — ``jax.make_mesh`` with every axis
+                              ``jax.sharding.AxisType.Auto``.
+  * ``cost_analysis``       — ``Compiled.cost_analysis()`` as a plain dict.
+  * ``pallas_compiler_params`` / ``prefetch_scalar_grid_spec`` — the TPU
+                              Pallas ``CompilerParams`` and
+                              ``PrefetchScalarGridSpec``.
+  * ``ragged_all_to_all``   — ``jax.lax.ragged_all_to_all``.
 """
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
 
-# --- shard_map -------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                     # jax >= 0.5
-    shard_map = jax.shard_map
-else:                                             # jax 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def _shard_map_uncheck_kwargs() -> dict:
-    """The kwarg that disables shard_map's replication checking, under its
-    current name: ``check_rep`` (0.4.x/0.5) became ``check_vma`` later."""
-    import inspect
-    params = inspect.signature(shard_map).parameters
-    for name in ("check_rep", "check_vma"):
-        if name in params:
-            return {name: False}
-    return {}
+shard_map = jax.shard_map
+ragged_all_to_all = jax.lax.ragged_all_to_all
 
 
 def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off.
-
-    Needed whenever the mapped body contains a ``pallas_call`` (the ftIMM
-    kernels): 0.4.x has no replication rule for it and raises
-    NotImplementedError under the default ``check_rep=True``."""
+    """``shard_map`` with replication checking off (needed around the
+    ``pallas_call`` bodies of the ftIMM kernels)."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **_shard_map_uncheck_kwargs())
+                     check_vma=False)
 
-
-# --- mesh construction -----------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None) -> jax.sharding.Mesh:
-    """Portable ``jax.make_mesh`` with Auto axis types on every version."""
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-# --- ragged all-to-all -----------------------------------------------------
-
-# The true ragged collective (newer jax; backend support varies).  ``None``
-# on the pinned 0.4.x line.  Consumers must treat availability of the symbol
-# as necessary but NOT sufficient: ``core.gemm.collective`` runs a concrete
-# round-trip probe on the actual mesh before trusting it, and falls back to
-# the dense all_gather/psum_scatter realization otherwise.
-ragged_all_to_all = getattr(jax.lax, "ragged_all_to_all", None)
-
-
-# --- compiled cost analysis ------------------------------------------------
-
-def normalize_cost_analysis(cost) -> dict:
-    """``Compiled.cost_analysis()`` -> one flat dict on every version.
-
-    jax 0.4.x returns ``[{...}]`` (one dict per program); newer versions
-    return the dict directly.  Missing/empty analyses normalize to ``{}``.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        merged: dict = {}
-        for entry in cost:
-            if entry:
-                merged.update(entry)
-        return merged
-    return dict(cost)
+    """``jax.make_mesh`` with plain Auto axes."""
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names), devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def cost_analysis(compiled) -> dict:
-    """Run + normalize ``compiled.cost_analysis()``."""
-    return normalize_cost_analysis(compiled.cost_analysis())
+    """``compiled.cost_analysis()`` as a dict (``{}`` when XLA gives none)."""
+    return dict(compiled.cost_analysis() or {})
 
-
-# --- pallas compiler params ------------------------------------------------
 
 def pallas_compiler_params(**kwargs):
-    """Build TPU Pallas compiler params under either class name."""
+    """TPU Pallas compiler params."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return pltpu.CompilerParams(**kwargs)
 
 
 def prefetch_scalar_grid_spec(*, num_scalar_prefetch: int, grid, in_specs,
                               out_specs, scratch_shapes=()):
-    """Scalar-prefetch grid spec (index maps may read int32 operands).
-
-    ``pltpu.PrefetchScalarGridSpec`` has kept its name across the 0.4.x ->
-    current line; wrapped here anyway so any future rename/move lands in one
-    place (repo compat policy — see module docstring)."""
+    """Scalar-prefetch grid spec (index maps may read int32 operands)."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalar_prefetch, grid=grid,

@@ -105,13 +105,6 @@ def _rand(shape, dtype, seed: int = 0):
                              jnp.float32).astype(dtype)
 
 
-def _widen(x: jax.Array) -> jax.Array:
-    """XLA-engine operand fixup: the reference dots have no narrow-int path
-    on the pinned jax, so itemsize-1 operands run upcast (outside the timed
-    thunk; the engine differentiates candidates through padding only)."""
-    return x.astype(jnp.float32) if jnp.dtype(x.dtype).itemsize == 1 else x
-
-
 @dataclass(frozen=True)
 class TuneResult:
     """Outcome of one measured search.
@@ -225,15 +218,11 @@ def _jit_batched_ref(out_dtype_name: str, a_ndim: int, b_ndim: int):
 @functools.lru_cache(maxsize=None)
 def _jit_ragged_ref(out_dtype_name: str):
     od = jnp.dtype(out_dtype_name)
-    rd = getattr(jax.lax, "ragged_dot", None)
-    if rd is None:  # pragma: no cover - every supported jax ships ragged_dot
-        return jax.jit(functools.partial(_ref.ragged_matmul_ref,
-                                         out_dtype=od))
 
     def f(x, w, offsets):
         sizes = jnp.diff(offsets).astype(jnp.int32)
-        return rd(x, w, sizes,
-                  preferred_element_type=jnp.float32).astype(od)
+        return jax.lax.ragged_dot(
+            x, w, sizes, preferred_element_type=jnp.float32).astype(od)
 
     return jax.jit(f)
 
@@ -310,7 +299,6 @@ def _dense_runner(engine, a, b, plan, out_dtype, epi: Epilogue | None = None):
         else:
             mp, kp, np_ = m, k, n
             a_p, b_p = a, b
-        a_p, b_p = _widen(a_p), _widen(b_p)
         fn = _jit_dense_ref(jnp.dtype(out_dtype).name)
         passes = [] if epi is None else _tail_passes(epi, out_dtype, fused)
         return (("xla", mp, kp, np_, epi, fused),
@@ -347,7 +335,6 @@ def _batched_runner(engine, a, b, plan, out_dtype):
         else:
             mp, kp, np_ = m, k, n
             a_p, b_p = a, b
-        a_p, b_p = _widen(a_p), _widen(b_p)
         fn = _jit_batched_ref(jnp.dtype(out_dtype).name, a.ndim, b.ndim)
         return ("xla", mp, kp, np_), (lambda: fn(a_p, b_p))
     interp = engine == "pallas_interpret"
@@ -363,8 +350,7 @@ def _ragged_runner(engine, x, w, offsets, plan, out_dtype, ragged):
         # dW: x (T, D), w is dy (T, F); the ragged dim is the contraction.
         if engine == "xla":
             fn = _jit_ragged_dw_ref(jnp.dtype(out_dtype).name)
-            xw, ww = _widen(x), _widen(w)
-            return ("xla", "dw"), (lambda: fn(xw, ww, offsets))
+            return ("xla", "dw"), (lambda: fn(x, w, offsets))
         interp = engine == "pallas_interpret"
         sig = ("pl", plan.bm, plan.bn, plan.bk, interp)
         return sig, (lambda: _ops.ragged_gemm_dw(
@@ -378,9 +364,8 @@ def _ragged_runner(engine, x, w, offsets, plan, out_dtype, ragged):
         tp = ceil_to(total, bm)
         x_p = jnp.pad(x, ((0, tp - total), (0, 0)))
         offs = offsets.at[-1].set(tp)       # pad rows ride the last group
-        x_p, w_p = _widen(x_p), _widen(w)
         fn = _jit_ragged_ref(jnp.dtype(out_dtype).name)
-        return ("xla", tp), (lambda: fn(x_p, w_p, offs))
+        return ("xla", tp), (lambda: fn(x_p, w, offs))
     interp = engine == "pallas_interpret"
     sig = ("pl", bm, bn, bk, interp)
     return sig, (lambda: _ops.ragged_gemm(

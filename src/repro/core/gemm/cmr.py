@@ -31,13 +31,16 @@ def cdiv(x: int, b: int) -> int:
 
 @dataclass(frozen=True)
 class TpuSpec:
-    """TPU v5e per-chip constants (targets; container runs CPU)."""
+    """Per-chip constants of one TPU generation (defaults: v5e)."""
     name: str = "tpu_v5e"
     peak_flops_bf16: float = 197e12
     peak_flops_fp32: float = 98.5e12        # MXU fp32 ~ half bf16 rate
     peak_flops_int8: float = 394e12         # int8 OPS ~ 2x bf16 rate
     hbm_bw: float = 819e9                   # bytes/s
-    vmem_budget: int = 16 * 1024 * 1024     # usable VMEM per core (conservative)
+    # Scoped-VMEM limit every ftIMM kernel asks Mosaic for
+    # (``vmem_limit_bytes``); v5e has 128 MiB of VMEM, the default scope
+    # is 16 MiB.
+    vmem_limit: int = 32 * 1024 * 1024
     lane: int = 128                          # vreg lanes / MXU width
     sublane_fp32: int = 8
     sublane_bf16: int = 16
@@ -46,6 +49,13 @@ class TpuSpec:
     ici_bw_per_link: float = 50e9           # bytes/s per ICI link
     ici_links: int = 4                      # usable links/chip on a 2D torus
     num_chips: int = 1
+
+    @property
+    def vmem_budget(self) -> int:
+        """What the planner admits a tiling's full per-step footprint
+        against (``analysis.contracts.vmem_footprint``): half the compiler
+        limit, the rest left to Mosaic's own temporaries."""
+        return self.vmem_limit // 2
 
     def peak_flops(self, dtype_bytes: int) -> float:
         """Peak MXU rate for the *compute* element width.  1-byte operands
@@ -92,6 +102,31 @@ class TpuSpec:
 
 
 TPU_V5E = TpuSpec()
+
+# Specs keyed by ``jax.Device.device_kind``.  A v5e chip reports itself as
+# "TPU v5 lite".
+SPECS_BY_KIND = {"TPU v5 lite": TPU_V5E, "TPU v5e": TPU_V5E}
+
+
+def spec_for_kind(kind: str) -> TpuSpec:
+    """The spec of a TPU ``device_kind``; an unknown kind is an error, not
+    a silent v5e default."""
+    try:
+        return SPECS_BY_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"no TpuSpec for TPU device kind {kind!r} (known: "
+            f"{', '.join(sorted(SPECS_BY_KIND))})") from None
+
+
+def device_spec() -> TpuSpec:
+    """The spec of this process's first device.  A TPU is looked up by its
+    kind; any other backend (the CPU test host) plans against v5e."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    return spec_for_kind(dev.device_kind)
 
 
 def upper_bound_fraction(m: int, n: int, k: int, spec: TpuSpec = TPU_V5E) -> float:

@@ -9,16 +9,16 @@ surrounding GEMM two ways:
 
 **Exchange realizations** (``exchange_method``):
 
-  * ``"primitive"`` — ``jax.lax.ragged_all_to_all`` (newer jax, backend
-    support varies): each shard ships ONLY the bytes of the owned windows,
+  * ``"primitive"`` — ``jax.lax.ragged_all_to_all`` (backend support
+    varies): each shard ships ONLY the bytes of the owned windows,
     send/recv offsets derived from the prefix sums.  Availability of the
     symbol is necessary but not sufficient — a concrete round-trip probe on
     the actual mesh must pass before it is trusted (``REPRO_RAGGED_A2A=auto``,
     the default; ``=primitive`` forces, ``=dense`` disables).
   * ``"dense"`` — the portable realization: one ``all_gather`` of the rows
     in, a scatter + ``psum_scatter`` back (windows are disjoint and cover
-    [0, T), so the sum just merges them).  Works on every jax/backend the
-    repo supports; moves more bytes but the same number of collectives.
+    [0, T), so the sum just merges them).  Works on every backend; moves
+    more bytes but the same number of collectives.
 
 **Schedules** (the ``Placement.schedule`` axis the tuner prices):
 
@@ -91,8 +91,6 @@ def _primitive_probe_ok(mesh: Mesh, ax: str) -> bool:
     real mesh and require it to reproduce the input exactly.  Any failure
     (missing backend lowering, semantics drift, compile error) means the
     dense realization is used instead — the probe is the contract."""
-    if compat.ragged_all_to_all is None:
-        return False
     nc = int(mesh.shape[ax])
     if nc <= 1:
         return False

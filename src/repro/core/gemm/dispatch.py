@@ -38,12 +38,15 @@ _REF = {"nn": _ref.matmul_nn, "tn": _ref.matmul_tn, "nt": _ref.matmul_nt}
 
 
 # ---------------------------------------------------------------------------
-# Dispatch fallback ladder: when a kernel fails (a real launch/trace error
-# or a chaos-injected one), the call degrades one rung instead of taking
-# the request down — pallas -> the XLA oracle with identical fp32-
-# accumulation semantics, fused epilogue -> the unfused two-pass spelling.
-# Every degraded serving is counted in ``tuner.plan_mode_stats()`` and the
-# first occurrence of each rung is logged once.
+# Dispatch fallback ladder: when a chaos-injected fault (``runtime.chaos``)
+# fails a kernel, the call degrades one rung instead of taking the request
+# down — pallas -> the XLA oracle with identical fp32-accumulation
+# semantics, fused epilogue -> the unfused two-pass spelling.  Only
+# ``ChaosError`` is caught: a real Mosaic / lowering / launch error
+# propagates, so a kernel that cannot run on the device is never hidden
+# behind an XLA serving.  Every degraded serving is counted in
+# ``tuner.plan_mode_stats()`` and the first occurrence of each rung is
+# logged once.
 # ---------------------------------------------------------------------------
 
 _WARNED_RUNGS: set = set()
@@ -151,6 +154,12 @@ def _b_bytes(a: jax.Array, b: jax.Array) -> int | None:
     return None if bb == jnp.dtype(a.dtype).itemsize else bb
 
 
+def _plan_trans(trans: str) -> str:
+    """The layout the planners key on: only "tn" (M on A's lane axis)
+    changes the legal tilings, so "nt" shares the "nn" plans."""
+    return "tn" if trans == "tn" else "nn"
+
+
 def _mkn(trans: str, a_shape, b_shape):
     if trans == "nn":
         (m, k), (_, n) = a_shape, b_shape
@@ -169,7 +178,8 @@ def _run_planned(a: jax.Array, b: jax.Array, trans: str, out_dtype,
     out_bytes = jnp.dtype(out_dtype).itemsize
     bb = _b_bytes(a, b)
     plan = plan_gemm(m, k, n, in_bytes, out_bytes, epi_ops=epi.num_ops,
-                     b_bytes=bb)
+                     b_bytes=bb, trans=_plan_trans(trans),
+                     epilogue=None if epi.is_identity else epi)
     _verify("dense", (m, k, n), plan, in_bytes, out_bytes, epi=epi,
             trans=trans, b_bytes=bb)
     note_plan_use("dense", plan)
@@ -180,7 +190,7 @@ def _run_planned(a: jax.Array, b: jax.Array, trans: str, out_dtype,
                 a, b, trans=trans, out_dtype=out_dtype, interpret=interpret,
                 **plan.kernel_kwargs(),
             )
-        except Exception as e:
+        except _chaos.ChaosError as e:
             _degraded("dense", "pallas->xla", e)
             return _xla_dense(a, b, trans, out_dtype)
     note_epilogue("dense", plan.fuse)
@@ -192,7 +202,7 @@ def _run_planned(a: jax.Array, b: jax.Array, trans: str, out_dtype,
                 epilogue=epi, bias=bias, residual=residual, scale=scale,
                 **plan.kernel_kwargs(),
             )
-        except Exception as e:
+        except _chaos.ChaosError as e:
             # Fused kernel failed: next rung is the unfused spelling below
             # (identity kernel + separate tail), NOT straight to XLA.
             _degraded("dense", "fused->unfused", e)
@@ -203,7 +213,7 @@ def _run_planned(a: jax.Array, b: jax.Array, trans: str, out_dtype,
         _chaos.fire("kernel")
         z = _ops.gemm(a, b, trans=trans, out_dtype=jnp.float32,
                       interpret=interpret, **plan.kernel_kwargs())
-    except Exception as e:
+    except _chaos.ChaosError as e:
         _degraded("dense", "pallas->xla", e)
         return _xla_dense(a, b, trans, out_dtype, epi, bias, residual, scale)
     return epi.apply(z, bias=bias, residual=residual,
@@ -318,7 +328,7 @@ def _quant_fn(qcfg: "_quant.QuantConfig", trans: str, out_dtype_name: str,
             in_bytes = jnp.dtype(a_q.dtype).itemsize
             plan = plan_gemm(m, k, n, in_bytes, out_dtype.itemsize,
                              epi_ops=qepi.num_ops,
-                             b_bytes=_b_bytes(a_q, w_q))
+                             b_bytes=_b_bytes(a_q, w_q), epilogue=qepi)
             note_plan_use("dense", plan)
             note_epilogue("dense", True)
             z = _REF[trans](a_q.astype(jnp.float32),
@@ -417,7 +427,9 @@ def matmul(a: jax.Array, b: jax.Array, *, trans: str = "nn",
         in_bytes = jnp.dtype(a.dtype).itemsize
         bb = _b_bytes(a, b)
         plan = plan_gemm(m, k, n, in_bytes, out_dtype.itemsize,
-                         epi_ops=epi.num_ops, b_bytes=bb)
+                         epi_ops=epi.num_ops, b_bytes=bb,
+                         trans=_plan_trans(trans),
+                         epilogue=None if epi.is_identity else epi)
         _verify("dense", (m, k, n), plan, in_bytes, out_dtype.itemsize,
                 epi=epi, trans=trans, b_bytes=bb)
         note_plan_use("dense", plan)
@@ -467,7 +479,8 @@ def _run_planned_batched(a: jax.Array, b: jax.Array, trans: str, out_dtype,
     g, m, k, n, shared = _batched_mkns(trans, a, b)
     in_bytes = jnp.dtype(a.dtype).itemsize
     out_bytes = jnp.dtype(out_dtype).itemsize
-    plan = plan_batched_gemm(g, m, k, n, in_bytes, out_bytes, shared)
+    plan = plan_batched_gemm(g, m, k, n, in_bytes, out_bytes, shared,
+                             trans=_plan_trans(trans))
     _verify("batched", (g, m, k, n), plan, in_bytes, out_bytes, trans=trans)
     note_plan_use("batched", plan)
     if backend == "xla":
@@ -479,7 +492,7 @@ def _run_planned_batched(a: jax.Array, b: jax.Array, trans: str, out_dtype,
             dim_order=plan.dim_order, trans=trans, out_dtype=out_dtype,
             edge=plan.edge, interpret=(backend == "pallas_interpret"),
         )
-    except Exception as e:
+    except _chaos.ChaosError as e:
         _degraded("batched", "pallas->xla", e)
         return _ref_batched(_wide(a), _wide(b), trans, out_dtype)
 
@@ -545,7 +558,7 @@ def _batched_bias_fn(out_dtype_name: str, backend: str):
         g, m, k, n, shared = _batched_mkns("nn", a, b)
         in_bytes = jnp.dtype(a.dtype).itemsize
         plan = plan_batched_gemm(g, m, k, n, in_bytes, out_dtype.itemsize,
-                                 shared, epi_ops=epi.num_ops)
+                                 shared, epi_ops=epi.num_ops, epilogue=epi)
         _verify("batched", (g, m, k, n), plan, in_bytes, out_dtype.itemsize,
                 epi=epi)
         note_plan_use("batched", plan)
@@ -668,7 +681,7 @@ def _make_swiglu_fn(out_dtype, backend: str, family: str, plan_fn, run_fn,
             try:
                 _chaos.fire("kernel_fused")
                 return fused_kernel(x, wg, wu, plan)
-            except Exception as e:
+            except _chaos.ChaosError as e:
                 # Ladder rung: the one-launch SwiGLU kernel failed — fall
                 # back to the two planned GEMMs + elementwise tail (whose
                 # own pallas->xla rung guards the panels' kernels).
@@ -704,7 +717,7 @@ def _swiglu_fn(out_dtype_name: str, backend: str):
     def plan_fn(x, wg):
         plan = plan_gemm(x.shape[0], x.shape[1], wg.shape[1],
                          jnp.dtype(x.dtype).itemsize, out_dtype.itemsize,
-                         epi_ops=2)
+                         epi_ops=2, swiglu=True)
         _verify("dense", (x.shape[0], x.shape[1], wg.shape[1]), plan,
                 jnp.dtype(x.dtype).itemsize, out_dtype.itemsize, swiglu=True)
         note_plan_use("dense", plan)
@@ -745,7 +758,8 @@ def _grouped_swiglu_fn(out_dtype_name: str, backend: str):
     def plan_fn(x, wg):
         plan = plan_batched_gemm(wg.shape[0], x.shape[-2], x.shape[-1],
                                  wg.shape[2], jnp.dtype(x.dtype).itemsize,
-                                 out_dtype.itemsize, "none", epi_ops=2)
+                                 out_dtype.itemsize, "none", epi_ops=2,
+                                 swiglu=True)
         _verify("batched",
                 (wg.shape[0], x.shape[-2], x.shape[-1], wg.shape[2]), plan,
                 jnp.dtype(x.dtype).itemsize, out_dtype.itemsize, swiglu=True)
@@ -789,16 +803,11 @@ def _float0_zeros(x: jax.Array):
 def _xla_ragged(x: jax.Array, w: jax.Array, offsets: jax.Array,
                 trans: str, out_dtype) -> jax.Array:
     """XLA engine for the ragged product: ``jax.lax.ragged_dot`` (one pass
-    over the rows) where the runtime has it, else the masked per-group
-    oracle (G full-width GEMMs — correct but O(G) costlier)."""
-    rd = getattr(jax.lax, "ragged_dot", None)
-    if rd is None:  # pragma: no cover - every supported jax ships ragged_dot
-        return _ref.ragged_matmul_ref(x, w, offsets, trans=trans,
-                                      out_dtype=out_dtype)
+    over the rows)."""
     wx = w if trans == "nn" else jnp.swapaxes(w, 1, 2)
     sizes = jnp.diff(offsets).astype(jnp.int32)
-    return rd(x, wx, sizes,
-              preferred_element_type=jnp.float32).astype(out_dtype)
+    return jax.lax.ragged_dot(
+        x, wx, sizes, preferred_element_type=jnp.float32).astype(out_dtype)
 
 
 def _row_groups(offsets: jax.Array, t: int) -> jax.Array:
@@ -833,7 +842,8 @@ def _run_planned_ragged(x: jax.Array, w: jax.Array, offsets: jax.Array,
     out_bytes = jnp.dtype(out_dtype).itemsize
     bb = _b_bytes(x, w)
     plan = plan_ragged_gemm(g, x.shape[0], k, n, in_bytes, out_bytes,
-                            b_bytes=bb)
+                            b_bytes=bb,
+                            epilogue=None if epi.is_identity else epi)
     _verify("ragged", (g, x.shape[0], k, n), plan, in_bytes, out_bytes,
             trans=trans, epi=None if epi.is_identity else epi, b_bytes=bb)
     note_plan_use("ragged", plan)
@@ -849,7 +859,7 @@ def _run_planned_ragged(x: jax.Array, w: jax.Array, offsets: jax.Array,
             out_dtype=out_dtype, interpret=(backend == "pallas_interpret"),
             epilogue=None if epi.is_identity else epi, bias=bias,
             scale=scale)
-    except Exception as e:
+    except _chaos.ChaosError as e:
         _degraded("ragged", "pallas->xla", e)
         return _xla_ragged_epi(x, w, offsets, trans, out_dtype, epi, bias,
                                scale)
@@ -862,9 +872,7 @@ def _xla_ragged_epi(x: jax.Array, w: jax.Array, offsets: jax.Array,
     — both the CPU execution path and the ragged pallas->xla ladder rung."""
     if epi.is_identity:
         return _xla_ragged(x, w, offsets, trans, out_dtype)
-    # ragged_dot has no narrow-int path on the pinned jax: upcast the
-    # quantized operand(s); the values are identical by construction.
-    z = _xla_ragged(_wide(x), _wide(w), offsets, trans, jnp.float32)
+    z = _xla_ragged(x, w, offsets, trans, jnp.float32)
     t = x.shape[0]
     return epi.apply(
         z,
@@ -886,16 +894,14 @@ def _run_planned_ragged_dw(x: jax.Array, dy: jax.Array, offsets: jax.Array,
             in_bytes, out_bytes, ragged="k")
     note_plan_use("ragged", plan)
     if backend == "xla":
-        # Per-group outputs have no ragged_dot analogue on the pinned jax
-        # (ragged_dot_general is newer); the masked per-group contraction
-        # is the XLA engine here.
+        # The masked per-group contraction is the XLA engine for dW.
         return _ref.ragged_matmul_dw_ref(x, dy, offsets, out_dtype=out_dtype)
     try:
         _chaos.fire("kernel")
         return _ops.ragged_gemm_dw(
             x, dy, offsets, bm=plan.bm, bn=plan.bn, bk=plan.bk,
             out_dtype=out_dtype, interpret=(backend == "pallas_interpret"))
-    except Exception as e:
+    except _chaos.ChaosError as e:
         _degraded("ragged", "pallas->xla", e)
         return _ref.ragged_matmul_dw_ref(_wide(x), _wide(dy), offsets,
                                          out_dtype=out_dtype)
@@ -1057,7 +1063,8 @@ def _ragged_swiglu_fn(out_dtype_name: str, backend: str):
     def _plan(x, wg):
         in_bytes = jnp.dtype(x.dtype).itemsize
         plan = plan_ragged_gemm(wg.shape[0], x.shape[0], wg.shape[1],
-                                wg.shape[2], in_bytes, out_dtype.itemsize)
+                                wg.shape[2], in_bytes, out_dtype.itemsize,
+                                swiglu=True)
         _verify("ragged", (wg.shape[0], x.shape[0], wg.shape[1],
                            wg.shape[2]), plan, in_bytes, out_dtype.itemsize,
                 swiglu=True)
@@ -1077,7 +1084,7 @@ def _ragged_swiglu_fn(out_dtype_name: str, backend: str):
                 x, wg, wu, offsets, bm=plan.bm, bn=plan.bn, bk=plan.bk,
                 out_dtype=out_dtype,
                 interpret=(backend == "pallas_interpret"))
-        except Exception as e:
+        except _chaos.ChaosError as e:
             _degraded("ragged", "fused->unfused", e)
         a = _run_planned_ragged(x, wg, offsets, "nn", jnp.float32, backend)
         b = _run_planned_ragged(x, wu, offsets, "nn", jnp.float32, backend)

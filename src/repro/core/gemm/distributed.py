@@ -678,7 +678,9 @@ def _ep_executor_args(x_p, w, out_dtype, mesh, axis, schedule):
 
 
 def _ep_ladder(run, schedule: str, single):
-    """The EP fallback ladder: ring -> gather -> single-device.
+    """The EP fallback ladder: ring -> gather -> single-device, taken only
+    on an injected ``chaos.ChaosError`` — a real executor or collective
+    error propagates.
 
     ``run(schedule)`` builds + calls the sharded executor; ``single()`` is
     the last rung — the plain planned ragged op on the GLOBAL arrays, which
@@ -691,13 +693,13 @@ def _ep_ladder(run, schedule: str, single):
         try:
             _chaos.fire("ep_ring")
             return run("ring")
-        except Exception as e:
+        except _chaos.ChaosError as e:
             _degraded("ep", "ring->gather", e)
             schedule = "gather"
     try:
         _chaos.fire("ep_gather")
         return run(schedule)
-    except Exception as e:
+    except _chaos.ChaosError as e:
         _degraded("ep", "gather->single", e)
         return single()
 

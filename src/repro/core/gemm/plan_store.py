@@ -51,13 +51,15 @@ _RECORD_KEYS = frozenset({
 
 
 def device_kind() -> str:
-    """Canonical device kind of the timing device ("cpu", "tpu_v5e", ...)."""
-    try:
-        import jax
-        kind = jax.devices()[0].device_kind
-    except Exception:  # pragma: no cover - jax always importable in-repo
-        return "unknown"
-    return str(kind).strip().lower().replace(" ", "_")
+    """Canonical device kind of the timing device: the ``TpuSpec`` name of
+    a TPU ("tpu_v5e" for a chip reporting "TPU v5 lite"; an unknown TPU
+    kind raises), else the backend's own kind ("cpu")."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        from .cmr import spec_for_kind
+        return spec_for_kind(dev.device_kind).name
+    return str(dev.device_kind).strip().lower().replace(" ", "_")
 
 
 def shape_key(family: str, dims: tuple, in_bytes: int, out_bytes: int,

@@ -49,7 +49,8 @@ from dataclasses import dataclass, replace
 from . import plan_store
 from ...analysis import contracts
 from .cmr import (TPU_V5E, EpEstimate, PlanEstimate, TpuSpec, cdiv, ceil_to,
-                  estimate, estimate_batched, estimate_ep, estimate_ragged)
+                  device_spec, estimate, estimate_batched, estimate_ep,
+                  estimate_ragged)
 from .shapes import GemmClass, classify
 
 
@@ -149,11 +150,26 @@ class DistPlan(Plan):
         return self.placement.t_collective
 
 
-def _bm_candidates(m: int, sublane: int) -> list[int]:
-    cands = [c for c in (128, 256, 512, 1024) if c <= ceil_to(m, sublane)]
+def _bm_candidates(m: int, unit: int) -> list[int]:
+    """Row-block candidates, multiples of ``unit``
+    (``contracts.bm_unit``)."""
+    cands = [c for c in (128, 256, 512, 1024) if c <= ceil_to(m, unit)]
     if m < 128:
-        cands.append(ceil_to(m, sublane))
-    return sorted(set(cands)) or [ceil_to(m, sublane)]
+        cands.append(ceil_to(m, unit))
+    return sorted(set(cands)) or [ceil_to(m, unit)]
+
+
+def _fits(family: str, bm: int, bn: int, bk: int, *, in_bytes: int,
+          out_bytes: int, spec: TpuSpec, b_bytes: int | None = None,
+          ragged: str = "m", epilogue=None, swiglu: bool = False) -> bool:
+    """VMEM admission on the FULL per-step footprint — blocks, accumulator,
+    and the epilogue / SwiGLU inputs the kernel really carries
+    (``contracts.vmem_footprint``) — against the spec's budget, which keeps
+    headroom under the limit the kernels pass to the compiler."""
+    return contracts.vmem_footprint(
+        family, bm=bm, bn=bn, bk=bk, in_bytes=in_bytes, out_bytes=out_bytes,
+        ragged=ragged, epilogue=epilogue, swiglu=swiglu,
+        b_bytes=b_bytes) <= spec.vmem_budget
 
 
 def _bn_candidates(n: int, lane: int) -> list[int]:
@@ -173,14 +189,17 @@ def _bk_candidates(k: int) -> list[int]:
 
 
 def effective_spec(spec: TpuSpec) -> TpuSpec:
-    """Swap the stock default spec for its measured calibration, when the
-    persistent store carries one (``autotune.calibrate`` fits the achievable
-    flops fraction + effective HBM bandwidth from measured-vs-predicted
-    ratios).  Explicitly-passed custom specs are honored untouched — the
-    calibration corrects the *default* constants so shapes that were never
-    measured still plan against reality."""
+    """Swap the stock default spec for this device's spec
+    (``cmr.device_spec``: looked up by TPU kind, v5e on other backends) and
+    that for its measured calibration, when the persistent store carries
+    one (``autotune.calibrate`` fits the achievable flops fraction +
+    effective HBM bandwidth from measured-vs-predicted ratios).
+    Explicitly-passed custom specs are honored untouched — the calibration
+    corrects the *default* constants so shapes that were never measured
+    still plan against reality."""
     if spec is not TPU_V5E:
         return spec
+    spec = device_spec()
     cal = plan_store.get_store().calibration
     if cal is None:
         return spec
@@ -212,7 +231,8 @@ def gemm_candidates(m: int, k: int, n: int, in_bytes: int = 4,
                     out_bytes: int = 4,
                     spec: TpuSpec = TPU_V5E,
                     epi_ops: int = 0, *, verify: bool = True,
-                    b_bytes: int | None = None
+                    b_bytes: int | None = None, trans: str = "nn",
+                    epilogue=None, swiglu: bool = False
                     ) -> list[GemmPlan]:
     """Every VMEM-feasible candidate tiling for the dense GEMM, scored by
     the CMR model.  The candidate space is (blocking x dim order x edge
@@ -221,36 +241,43 @@ def gemm_candidates(m: int, k: int, n: int, in_bytes: int = 4,
     when the caller carries an epilogue (``epi_ops > 0``).  ``verify`` runs
     the static contract pre-check (``analysis.contracts.check_blocks``) so
     geometrically infeasible tilings are pruned BEFORE CMR pricing or
-    measured timing.  Never empty: when nothing fits the budget the
-    degenerate minimum tile is returned (and priced) as the only
-    candidate."""
+    measured timing.  ``trans`` sets the alignment unit of ``bm``
+    (``contracts.bm_unit``); ``epilogue``/``swiglu`` describe the extra inputs the
+    fused kernel carries, which VMEM admission counts (``_fits``).  Never
+    empty: when nothing fits the budget the degenerate minimum tile is
+    returned (and priced) as the only candidate."""
     cls = classify(m, k, n)
-    sublane = spec.sublane(in_bytes)
+    unit = contracts.bm_unit(trans, in_bytes, spec)
     cands: list[GemmPlan] = []
-    for bm in _bm_candidates(m, sublane):
+    for bm in _bm_candidates(m, unit):
         for bn in _bn_candidates(n, spec.lane):
             for bk in _bk_candidates(k):
                 for order in ("mn", "nm"):
                     if verify and contracts.errors(contracts.check_blocks(
                             "dense", (m, k, n), bm=bm, bn=bn, bk=bk,
                             dim_order=order, in_bytes=in_bytes,
-                            out_bytes=out_bytes, spec=spec)):
+                            out_bytes=out_bytes, trans=trans, spec=spec)):
                         continue
                     for edge in _edge_variants(m, k, n, bm, bn, bk):
                         for fuse in _fuse_variants(epi_ops):
+                            if not _fits("dense", bm, bn, bk,
+                                         in_bytes=in_bytes,
+                                         out_bytes=out_bytes, spec=spec,
+                                         b_bytes=b_bytes,
+                                         epilogue=epilogue if fuse else None,
+                                         swiglu=swiglu and fuse):
+                                continue
                             e = estimate(m, k, n, bm=bm, bn=bn, bk=bk,
                                          dim_order=order, in_bytes=in_bytes,
                                          out_bytes=out_bytes, edge=edge,
                                          epi_ops=epi_ops, epi_fused=fuse,
                                          b_bytes=b_bytes, spec=spec)
-                            if e.vmem_bytes > spec.vmem_budget:
-                                continue
                             cands.append(GemmPlan(
                                 bm=bm, bn=bn, bk=bk, dim_order=order,
                                 gemm_class=cls, est=e, edge=edge,
                                 fuse=fuse))
     if not cands:   # degenerate: nothing fit; shrink to minimum tiles
-        bm, bn, bk = min(128, ceil_to(m, sublane)), 128, 128
+        bm, bn, bk = min(128, ceil_to(m, unit)), 128, 128
         e = estimate(m, k, n, bm=bm, bn=bn, bk=bk, epi_ops=epi_ops,
                      in_bytes=in_bytes, out_bytes=out_bytes, b_bytes=b_bytes,
                      spec=spec)
@@ -261,41 +288,47 @@ def gemm_candidates(m: int, k: int, n: int, in_bytes: int = 4,
 def batched_candidates(g: int, m: int, k: int, n: int, in_bytes: int = 4,
                        out_bytes: int = 4, shared: str = "none",
                        spec: TpuSpec = TPU_V5E,
-                       epi_ops: int = 0, *, verify: bool = True
-                       ) -> list[GemmPlan]:
+                       epi_ops: int = 0, *, verify: bool = True,
+                       trans: str = "nn", epilogue=None,
+                       swiglu: bool = False) -> list[GemmPlan]:
     """Candidate tilings for the batched/grouped GEMM (same enumeration as
-    the dense family, including the edge-policy and epilogue-fusion forks
+    the dense family, including the edge-policy and epilogue-fusion forks,
+    the ``trans``-dependent ``bm`` unit, the full-footprint VMEM admission
     and the same static contract pre-check; the batch-aware estimator
     decides whether a shared panel earns cross-batch residency)."""
     cls = classify(m, k, n)
-    sublane = spec.sublane(in_bytes)
+    unit = contracts.bm_unit(trans, in_bytes, spec)
     shared_a, shared_b = shared == "a", shared == "b"
     cands: list[GemmPlan] = []
-    for bm in _bm_candidates(m, sublane):
+    for bm in _bm_candidates(m, unit):
         for bn in _bn_candidates(n, spec.lane):
             for bk in _bk_candidates(k):
                 for order in ("mn", "nm"):
                     if verify and contracts.errors(contracts.check_blocks(
                             "batched", (g, m, k, n), bm=bm, bn=bn, bk=bk,
                             dim_order=order, in_bytes=in_bytes,
-                            out_bytes=out_bytes, spec=spec)):
+                            out_bytes=out_bytes, trans=trans, spec=spec)):
                         continue
                     for edge in _edge_variants(m, k, n, bm, bn, bk):
                         for fuse in _fuse_variants(epi_ops):
+                            if not _fits("batched", bm, bn, bk,
+                                         in_bytes=in_bytes,
+                                         out_bytes=out_bytes, spec=spec,
+                                         epilogue=epilogue if fuse else None,
+                                         swiglu=swiglu and fuse):
+                                continue
                             e = estimate_batched(
                                 g, m, k, n, bm=bm, bn=bn, bk=bk,
                                 dim_order=order, shared_a=shared_a,
                                 shared_b=shared_b, in_bytes=in_bytes,
                                 out_bytes=out_bytes, edge=edge,
                                 epi_ops=epi_ops, epi_fused=fuse, spec=spec)
-                            if e.vmem_bytes > spec.vmem_budget:
-                                continue
                             cands.append(GemmPlan(
                                 bm=bm, bn=bn, bk=bk, dim_order=order,
                                 gemm_class=cls, est=e, edge=edge,
                                 fuse=fuse))
     if not cands:
-        bm, bn, bk = min(128, ceil_to(m, sublane)), 128, 128
+        bm, bn, bk = min(128, ceil_to(m, unit)), 128, 128
         e = estimate_batched(g, m, k, n, bm=bm, bn=bn, bk=bk,
                              shared_a=shared_a, shared_b=shared_b,
                              in_bytes=in_bytes, out_bytes=out_bytes,
@@ -323,8 +356,8 @@ def _ragged_tile_candidates(total: int, g: int, sublane: int) -> list[int]:
 def ragged_candidates(g: int, total: int, k: int, n: int, in_bytes: int = 4,
                       out_bytes: int = 4, ragged: str = "m",
                       spec: TpuSpec = TPU_V5E, *, verify: bool = True,
-                      b_bytes: int | None = None
-                      ) -> list[GemmPlan]:
+                      b_bytes: int | None = None, epilogue=None,
+                      swiglu: bool = False) -> list[GemmPlan]:
     """Candidate tilings for the ragged grouped GEMM: the ragged dimension's
     tile list comes from the *distribution* (mean group size), the dense
     dimensions from the shared dense lists.  No dim_order choice — the
@@ -338,7 +371,7 @@ def ragged_candidates(g: int, total: int, k: int, n: int, in_bytes: int = 4,
         bns, bks = _bn_candidates(n, spec.lane), _bk_candidates(k)
     elif ragged == "k":
         cls = classify(k, mean, n)
-        bms = _bm_candidates(k, sublane)
+        bms = _bm_candidates(k, spec.lane)      # D: minor axis of x's block
         bns, bks = _bn_candidates(n, spec.lane), \
             _ragged_tile_candidates(total, g, sublane)
     else:
@@ -352,16 +385,21 @@ def ragged_candidates(g: int, total: int, k: int, n: int, in_bytes: int = 4,
                         ragged=ragged, in_bytes=in_bytes,
                         out_bytes=out_bytes, spec=spec)):
                     continue
+                if not _fits("ragged", bm, bn, bk, in_bytes=in_bytes,
+                             out_bytes=out_bytes, spec=spec,
+                             b_bytes=b_bytes, ragged=ragged,
+                             epilogue=epilogue, swiglu=swiglu):
+                    continue
                 e = estimate_ragged(g, total, k, n, bm=bm, bn=bn, bk=bk,
                                     ragged=ragged, in_bytes=in_bytes,
                                     out_bytes=out_bytes, b_bytes=b_bytes,
                                     spec=spec)
-                if e.vmem_bytes > spec.vmem_budget:
-                    continue
                 cands.append(GemmPlan(bm=bm, bn=bn, bk=bk, gemm_class=cls,
                                       est=e))
     if not cands:
         bm, bn, bk = min(128, ceil_to(max(total, 1), sublane)), 128, 128
+        if ragged == "k":
+            bm, bk = 128, min(128, ceil_to(max(total, 1), sublane))
         e = estimate_ragged(g, total, k, n, bm=bm, bn=bn, bk=bk,
                             ragged=ragged, in_bytes=in_bytes,
                             out_bytes=out_bytes, b_bytes=b_bytes, spec=spec)
@@ -423,7 +461,11 @@ def shortlist(cands: list[GemmPlan], top_k: int) -> list[GemmPlan]:
 # ---------------------------------------------------------------------------
 
 def _plan_from_record(rec: dict, estimator, cls: GemmClass,
-                      spec: TpuSpec) -> GemmPlan | None:
+                      spec: TpuSpec, admit=None) -> GemmPlan | None:
+    """A cached record as a plan under ``spec``, or None when it is
+    shape-invalid.  ``admit(bm, bn, bk, fuse)`` is the caller's geometry and
+    full-footprint VMEM check; without one the base footprint of the fresh
+    estimate is held to the budget."""
     try:
         bm, bn, bk = int(rec["bm"]), int(rec["bn"]), int(rec["bk"])
         nsplit = int(rec.get("nsplit", 1))
@@ -439,9 +481,30 @@ def _plan_from_record(rec: dict, estimator, cls: GemmClass,
     e = estimator(bm, bn, bk, order, edge)
     if e is None or e.vmem_bytes > spec.vmem_budget:
         return None
+    if admit is not None and not admit(bm, bn, bk, fuse):
+        return None
     return GemmPlan(bm=bm, bn=bn, bk=bk, nsplit=nsplit, dim_order=order,
                     gemm_class=cls, est=e, mode="cached", edge=edge,
                     fuse=fuse)
+
+
+def _record_admit(family: str, dims: tuple, in_bytes: int, out_bytes: int,
+                  spec: TpuSpec, *, trans: str = "nn", ragged: str = "m",
+                  b_bytes: int | None = None, epilogue=None,
+                  swiglu: bool = False):
+    """``_plan_from_record``'s ``admit`` for one call: the cached tiling
+    must pass the block contracts under ``trans`` and fit VMEM with the
+    inputs its (fused) kernel carries."""
+    def admit(bm: int, bn: int, bk: int, fuse: bool) -> bool:
+        if contracts.errors(contracts.check_blocks(
+                family, dims, bm=bm, bn=bn, bk=bk, in_bytes=in_bytes,
+                out_bytes=out_bytes, ragged=ragged, trans=trans, spec=spec)):
+            return False
+        return _fits(family, bm, bn, bk, in_bytes=in_bytes,
+                     out_bytes=out_bytes, spec=spec, b_bytes=b_bytes,
+                     ragged=ragged, epilogue=epilogue if fuse else None,
+                     swiglu=swiglu and fuse)
+    return admit
 
 
 def _dtype_extra(b_bytes: int | None, base: str = "") -> str:
@@ -454,8 +517,9 @@ def _dtype_extra(b_bytes: int | None, base: str = "") -> str:
     return f"{base}+{frag}" if base else frag
 
 
-def _cached_dense(m, k, n, in_bytes, out_bytes, spec,
-                  b_bytes=None) -> GemmPlan | None:
+def _cached_dense(m, k, n, in_bytes, out_bytes, spec, b_bytes=None,
+                  trans="nn", epilogue=None,
+                  swiglu=False) -> GemmPlan | None:
     rec = plan_store.get_store().lookup(
         plan_store.shape_key("dense", (m, k, n), in_bytes, out_bytes,
                              extra=_dtype_extra(b_bytes)))
@@ -468,11 +532,15 @@ def _cached_dense(m, k, n, in_bytes, out_bytes, spec,
                         out_bytes=out_bytes, edge=edge, b_bytes=b_bytes,
                         spec=spec)
 
-    return _plan_from_record(rec, est, classify(m, k, n), spec)
+    admit = _record_admit("dense", (m, k, n), in_bytes, out_bytes, spec,
+                          trans=trans, b_bytes=b_bytes, epilogue=epilogue,
+                          swiglu=swiglu)
+    return _plan_from_record(rec, est, classify(m, k, n), spec, admit)
 
 
-def _cached_batched(g, m, k, n, in_bytes, out_bytes, shared,
-                    spec) -> GemmPlan | None:
+def _cached_batched(g, m, k, n, in_bytes, out_bytes, shared, spec,
+                    trans="nn", epilogue=None,
+                    swiglu=False) -> GemmPlan | None:
     rec = plan_store.get_store().lookup(
         plan_store.shape_key("batched", (g, m, k, n), in_bytes, out_bytes,
                              extra=f"shared:{shared}"))
@@ -485,11 +553,14 @@ def _cached_batched(g, m, k, n, in_bytes, out_bytes, shared,
                                 shared_b=shared == "b", in_bytes=in_bytes,
                                 out_bytes=out_bytes, edge=edge, spec=spec)
 
-    return _plan_from_record(rec, est, classify(m, k, n), spec)
+    admit = _record_admit("batched", (g, m, k, n), in_bytes, out_bytes, spec,
+                          trans=trans, epilogue=epilogue, swiglu=swiglu)
+    return _plan_from_record(rec, est, classify(m, k, n), spec, admit)
 
 
-def _cached_ragged(g, total, k, n, in_bytes, out_bytes, ragged,
-                   spec, b_bytes=None) -> GemmPlan | None:
+def _cached_ragged(g, total, k, n, in_bytes, out_bytes, ragged, spec,
+                   b_bytes=None, epilogue=None,
+                   swiglu=False) -> GemmPlan | None:
     rec = plan_store.get_store().lookup(
         plan_store.shape_key("ragged", (g, total, k, n), in_bytes, out_bytes,
                              extra=_dtype_extra(b_bytes,
@@ -507,7 +578,10 @@ def _cached_ragged(g, total, k, n, in_bytes, out_bytes, ragged,
                                out_bytes=out_bytes, b_bytes=b_bytes,
                                spec=spec)
 
-    return _plan_from_record(rec, est, cls, spec)
+    admit = _record_admit("ragged", (g, total, k, n), in_bytes, out_bytes,
+                          spec, ragged=ragged, b_bytes=b_bytes,
+                          epilogue=epilogue, swiglu=swiglu)
+    return _plan_from_record(rec, est, cls, spec, admit)
 
 
 def _cached_placed(family: str, dims: tuple, in_bytes: int, out_bytes: int,
@@ -781,6 +855,9 @@ def plan_gemm(
     axis: str | None = None,
     epi_ops: int = 0,
     b_bytes: int | None = None,
+    trans: str = "nn",
+    epilogue=None,
+    swiglu: bool = False,
 ) -> GemmPlan:
     """Pick the best tiling for C(M,N) += A(M,K) B(K,N) — and, when
     ``num_shards > 1``, the cross-chip strategy too: the returned plan is the
@@ -799,7 +876,12 @@ def plan_gemm(
     element width when it differs from A's — the weight-only quantized GEMMs
     (int8/int4-unpacked weights against bf16/fp32 activations) — so traffic,
     VMEM and the achievable peak are priced per dtype combination and cached
-    winners never leak across widths (the key carries a ``bb{n}`` extra)."""
+    winners never leak across widths (the key carries a ``bb{n}`` extra).
+
+    ``trans`` aligns ``bm`` to the lane tile where M is A's minor axis
+    ("tn"); ``epilogue`` (the ``Epilogue`` the fused kernel carries) and
+    ``swiglu`` (the fused gate/up pair: a second panel and accumulator)
+    make VMEM admission count those inputs too."""
     spec = effective_spec(spec)
     if num_shards > 1:
         opts = dense_placement_options(m, k, n, num_shards, in_bytes,
@@ -811,11 +893,13 @@ def plan_gemm(
         scored = [(o, replace(o.plan_local(in_bytes, out_bytes, spec),
                               placement=o.placement)) for o in opts]
         return _select_placed(scored)
-    cached = _cached_dense(m, k, n, in_bytes, out_bytes, spec, b_bytes)
+    cached = _cached_dense(m, k, n, in_bytes, out_bytes, spec, b_bytes,
+                           trans, epilogue, swiglu)
     if cached is not None:
         return cached
     return argmin_plan(gemm_candidates(m, k, n, in_bytes, out_bytes, spec,
-                                       epi_ops, b_bytes=b_bytes))
+                                       epi_ops, b_bytes=b_bytes, trans=trans,
+                                       epilogue=epilogue, swiglu=swiglu))
 
 
 @functools.lru_cache(maxsize=8192)
@@ -858,6 +942,9 @@ def plan_batched_gemm(
     num_shards: int = 1,
     axis: str | None = None,
     epi_ops: int = 0,
+    trans: str = "nn",
+    epilogue=None,
+    swiglu: bool = False,
 ) -> GemmPlan:
     """Pick the best tiling for the batched GEMM C(g) += A(g) B(g).
 
@@ -871,7 +958,8 @@ def plan_batched_gemm(
     ``num_shards > 1``: place the batched GEMM on the mesh — per-entry
     m_parallel (rows sharded, every shard streams all G panels) vs
     expert_parallel (the G dim sharded, tokens all-to-all'd to their owning
-    shard and back, priced by ``estimate_ep``)."""
+    shard and back, priced by ``estimate_ep``).  ``trans`` / ``epilogue``
+    / ``swiglu`` as in ``plan_gemm``."""
     spec = effective_spec(spec)
     if num_shards > 1:
         opts = batched_placement_options(g, m, k, n, num_shards, in_bytes,
@@ -884,11 +972,13 @@ def plan_batched_gemm(
         scored = [(o, replace(o.plan_local(in_bytes, out_bytes, spec),
                               placement=o.placement)) for o in opts]
         return _select_placed(scored)
-    cached = _cached_batched(g, m, k, n, in_bytes, out_bytes, shared, spec)
+    cached = _cached_batched(g, m, k, n, in_bytes, out_bytes, shared, spec,
+                             trans, epilogue, swiglu)
     if cached is not None:
         return cached
     return argmin_plan(batched_candidates(g, m, k, n, in_bytes, out_bytes,
-                                          shared, spec, epi_ops))
+                                          shared, spec, epi_ops, trans=trans,
+                                          epilogue=epilogue, swiglu=swiglu))
 
 
 @functools.lru_cache(maxsize=8192)
@@ -902,6 +992,8 @@ def plan_ragged_gemm(
     num_shards: int = 1,
     axis: str | None = None,
     b_bytes: int | None = None,
+    epilogue=None,
+    swiglu: bool = False,
 ) -> GemmPlan:
     """Pick the best tiling for a ragged grouped GEMM over G groups.
 
@@ -924,7 +1016,8 @@ def plan_ragged_gemm(
     panels per shard, paid for with the two all-to-all token-exchange legs
     priced by ``estimate_ep``).  EP wins exactly when the panel-traffic
     saving amortizes the exchange — few tokens against many/large expert
-    panels, the MoE decode regime.
+    panels, the MoE decode regime.  ``epilogue`` / ``swiglu`` as in
+    ``plan_gemm``.
     """
     spec = effective_spec(spec)
     if num_shards > 1:
@@ -939,11 +1032,12 @@ def plan_ragged_gemm(
                               placement=o.placement)) for o in opts]
         return _select_placed(scored)
     cached = _cached_ragged(g, total, k, n, in_bytes, out_bytes, ragged, spec,
-                            b_bytes)
+                            b_bytes, epilogue, swiglu)
     if cached is not None:
         return cached
     return argmin_plan(ragged_candidates(g, total, k, n, in_bytes, out_bytes,
-                                         ragged, spec, b_bytes=b_bytes))
+                                         ragged, spec, b_bytes=b_bytes,
+                                         epilogue=epilogue, swiglu=swiglu))
 
 
 @dataclass(frozen=True)

@@ -58,6 +58,17 @@ from .epilogue import IDENTITY, Epilogue
 DimOrder = Literal["mn", "nm"]
 
 
+def _compiler_params(*dimension_semantics: str):
+    """Mosaic params of every ftIMM kernel: the grid semantics plus an
+    explicit scoped-VMEM limit, the ``TpuSpec.vmem_limit`` of this device
+    — the same field the planner's VMEM budget derives from, so the
+    planner and the compiler agree on one number."""
+    from ...core.gemm.cmr import device_spec
+    return pallas_compiler_params(
+        dimension_semantics=dimension_semantics,
+        vmem_limit_bytes=device_spec().vmem_limit)
+
+
 def _k_limit(k_total: int, bk: int, kb_idx):
     """Valid contraction extent of K block ``kb_idx`` — ``bk`` for interior
     blocks, the remainder for the edge block, 0 for fully out-of-range blocks
@@ -249,9 +260,7 @@ def ftimm_gemm(
         out_specs=c_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), _acc_dtype(a.dtype, b.dtype))],
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*inputs)
 
@@ -387,10 +396,8 @@ def ftimm_gemm_grouped(
         out_specs=c_spec,
         out_shape=jax.ShapeDtypeStruct((gsize, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), _acc_dtype(a.dtype, b.dtype))],
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(*inputs)
 
@@ -584,9 +591,8 @@ def ftimm_gemm_ragged(
                                        _acc_dtype(x.dtype, w.dtype))],
         ),
         out_shape=jax.ShapeDtypeStruct((tp, np_), out_dtype),
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(
+            "arbitrary", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(group_ids, tile_ids, valid, group_offsets, *inputs)
 
@@ -664,9 +670,8 @@ def ftimm_gemm_ragged_swiglu(
                             pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((tp, np_), out_dtype),
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(
+            "arbitrary", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(group_ids, tile_ids, valid, group_offsets, x, w_gate, w_up)
 
@@ -741,9 +746,8 @@ def ftimm_gemm_ragged_dw(
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((num_groups, dp, fp), out_dtype),
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(
+            "arbitrary", "arbitrary", "arbitrary"),
         interpret=interpret,
     )(group_ids, tile_ids, valid, group_offsets, x, dy)
 
@@ -815,9 +819,8 @@ def ftimm_gemm_splitk(
         out_specs=c_spec,
         out_shape=jax.ShapeDtypeStruct((nsplit, m, n), acc_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(a, b)
     out = jnp.sum(partials, axis=0)
@@ -901,9 +904,7 @@ def ftimm_gemm_swiglu(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(x, w_gate, w_up)
 
@@ -956,9 +957,7 @@ def ftimm_gemm_grouped_swiglu(
         out_shape=jax.ShapeDtypeStruct((g, m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
                         pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_compiler_params(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        ),
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(x, w_gate, w_up)

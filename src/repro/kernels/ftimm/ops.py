@@ -16,10 +16,11 @@ TGEMM's fixed (m_s=6, n_a=96) kernel — and, since the epilogue generator,
 also whether the post-GEMM elementwise tail (bias/activation/residual/scale,
 ``kernel.Epilogue``) fuses into the accumulator flush.
 
-On non-TPU backends the kernels run in interpret mode (Python emulation of
-the kernel body) — correct but slow; the framework's model code therefore
-routes through ``repro.core.gemm.dispatch`` which picks the XLA path on CPU
-and the Pallas path on TPU.
+The kernels are compiled for the TPU.  Interpret mode (the Pallas
+interpreter, correct but slow, on any backend) runs only when the caller
+passes ``interpret=True``; nothing falls back to it.  The framework's model
+code routes through ``repro.core.gemm.dispatch``, which picks the XLA path
+on CPU and the Pallas path on TPU.
 """
 from __future__ import annotations
 
@@ -28,12 +29,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ...analysis.contracts import block_aligned
+from ...analysis.contracts import block_aligned, bm_unit
 from . import kernel as _k
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jax.Array, shape) -> jax.Array:
@@ -79,7 +76,8 @@ def bench(fn, *args, warmup: int = 1, repeats: int = 3) -> float:
 
 
 def _clamp_blocks(m: int, k: int, n: int, bm: int, bn: int, bk: int,
-                  nsplit: int, dtype) -> tuple[int, int, int, int]:
+                  nsplit: int, dtype,
+                  trans: str = "nn") -> tuple[int, int, int, int]:
     """Clamp a plan's blocks to the (rounded) problem extent.
 
     ``bk`` is clamped exactly like ``bm``/``bn`` — a K=64 problem under a
@@ -87,8 +85,10 @@ def _clamp_blocks(m: int, k: int, n: int, bm: int, bn: int, bk: int,
     such blocks for a different shape of the same signature family).  A
     clamped ``bk`` may leave ``nsplit`` covering fewer K blocks than splits;
     the split count shrinks with it (degenerating to 1 = the M-parallel
-    kernel)."""
-    bm_ = min(bm, _ceil_to(m, sublane(dtype)))
+    kernel).  ``bm`` is rounded to its alignment unit under ``trans``
+    (``contracts.bm_unit``)."""
+    unit = bm_unit(trans, jnp.dtype(dtype).itemsize)
+    bm_ = min(_ceil_to(bm, unit), _ceil_to(m, unit))
     bn_ = min(bn, _ceil_to(n, 128))
     bk_ = min(bk, _ceil_to(k, 128))
     if nsplit > 1:
@@ -114,7 +114,7 @@ def gemm(
     trans: str = "nn",
     dim_order: str = "mn",
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
     epilogue: "_k.Epilogue | None" = None,
     bias: jax.Array | None = None,
     residual: jax.Array | None = None,
@@ -126,15 +126,13 @@ def gemm(
     ``edge="masked"`` passes operands through unpadded (in-kernel edge
     tiles); ``edge="padded"`` pads to block multiples and slices back.
     ``scale`` is the (N,) dequant vector when ``epilogue.scale_vec``."""
-    if interpret is None:
-        interpret = _auto_interpret()
     if edge not in ("masked", "padded"):
         raise ValueError(f"unknown edge policy: {edge!r}")
     epilogue = _k.IDENTITY if epilogue is None else epilogue
     out_dtype = out_dtype or a.dtype
     m, k, n = _k._mkn(trans, a.shape, b.shape)
     bm_, bn_, bk_, nsplit = _clamp_blocks(m, k, n, bm, bn, bk, nsplit,
-                                          a.dtype)
+                                          a.dtype, trans)
 
     if edge == "padded":
         mp, np_ = _ceil_to(m, bm_), _ceil_to(n, bn_)
@@ -188,7 +186,7 @@ def batched_gemm(
     trans: str = "nn",
     dim_order: str = "mn",
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
     epilogue: "_k.Epilogue | None" = None,
     bias: jax.Array | None = None,
     residual: jax.Array | None = None,
@@ -201,14 +199,13 @@ def batched_gemm(
     the kernel on unpadded per-group panels; ``edge="padded"`` is the legacy
     pad/slice path.  ``bias`` and the dequant ``scale`` vector are (N,)
     shared across the batch or (G, N) per group; ``residual`` (G, M, N)."""
-    if interpret is None:
-        interpret = _auto_interpret()
     if edge not in ("masked", "padded"):
         raise ValueError(f"unknown edge policy: {edge!r}")
     epilogue = _k.IDENTITY if epilogue is None else epilogue
     out_dtype = out_dtype or a.dtype
     m, k, n = _k._mkn(trans, a.shape[-2:], b.shape[-2:])
-    bm_, bn_, bk_, _ = _clamp_blocks(m, k, n, bm, bn, bk, 1, a.dtype)
+    bm_, bn_, bk_, _ = _clamp_blocks(m, k, n, bm, bn, bk, 1, a.dtype,
+                                     trans)
 
     if edge == "padded":
         mp, np_, kp = _ceil_to(m, bm_), _ceil_to(n, bn_), _ceil_to(k, bk_)
@@ -257,13 +254,11 @@ def gemm_swiglu(
     bn: int = 128,
     bk: int = 128,
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
     edge: str = "masked",
 ) -> jax.Array:
     """Dense fused SwiGLU pair: silu(x @ Wg) * (x @ Wu) in one launch — the
     dense MLP's gate/up projections without the separate silu/mul passes."""
-    if interpret is None:
-        interpret = _auto_interpret()
     if edge not in ("masked", "padded"):
         raise ValueError(f"unknown edge policy: {edge!r}")
     out_dtype = out_dtype or x.dtype
@@ -294,13 +289,11 @@ def batched_gemm_swiglu(
     bn: int = 128,
     bk: int = 128,
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
     edge: str = "masked",
 ) -> jax.Array:
     """Grouped fused SwiGLU pair — the capacity-mode MoE gate/up projections
     (E, C, D) @ (E, D, F) as ONE launch with the silu(gate)*up epilogue."""
-    if interpret is None:
-        interpret = _auto_interpret()
     if edge not in ("masked", "padded"):
         raise ValueError(f"unknown edge policy: {edge!r}")
     out_dtype = out_dtype or x.dtype
@@ -368,7 +361,7 @@ def ragged_gemm(
     bk: int = 128,
     trans: str = "nn",
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
     epilogue: "_k.Epilogue | None" = None,
     bias: jax.Array | None = None,
     scale: jax.Array | None = None,
@@ -381,8 +374,6 @@ def ragged_gemm(
     here).  Pads rows/cols to block multiples, builds the visit list, runs the
     scalar-prefetch kernel, un-pads.  ``bias`` / dequant ``scale`` are
     per-expert (G, N) vectors applied at the flush (``epilogue`` flags)."""
-    if interpret is None:
-        interpret = _auto_interpret()
     epilogue = _k.IDENTITY if epilogue is None else epilogue
     out_dtype = out_dtype or x.dtype
     t_rows, k = x.shape
@@ -433,12 +424,10 @@ def ragged_gemm_swiglu(
     bn: int = 128,
     bk: int = 128,
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Fused-epilogue ragged pair: silu(x @ Wg_g) * (x @ Wu_g) per group, one
     kernel launch (same contract as ``ragged_gemm``)."""
-    if interpret is None:
-        interpret = _auto_interpret()
     out_dtype = out_dtype or x.dtype
     t_rows, k = x.shape
     g, kw, n = w_gate.shape
@@ -482,15 +471,13 @@ def ragged_gemm_dw(
     bn: int = 128,
     bk: int = 128,
     out_dtype=None,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Ragged T2 grouped GEMM: dW[g] = x[rows_g].T @ dy[rows_g] -> (G, D, F).
 
     ``bk`` tiles the ragged (token) dimension — the contraction; ``bm``/``bn``
     tile the per-group (D, F) output panel.  Same offsets contract as
     ``ragged_gemm``; empty groups yield zero panels."""
-    if interpret is None:
-        interpret = _auto_interpret()
     out_dtype = out_dtype or x.dtype
     t_rows, d = x.shape
     t2, f = dy.shape
@@ -500,7 +487,8 @@ def ragged_gemm_dw(
         return jnp.zeros((g, d, f), out_dtype)
 
     bk_ = min(bk, _ceil_to(t_rows, sublane(x.dtype)))   # ragged row tiles
-    bm_ = min(bm, _ceil_to(d, sublane(x.dtype)))
+    # D is the minor axis of the (bk, bm) x block: bm follows the lane rule.
+    bm_ = min(_ceil_to(bm, 128), _ceil_to(d, 128))
     bn_ = min(bn, _ceil_to(f, 128))
     # Verifier-driven zero-copy edge path (ragged axis = contraction here).
     if block_aligned((t_rows, d, f), (bk_, bm_, bn_)):

@@ -24,10 +24,9 @@ import jax.numpy as jnp
 
 from ..configs import SHAPES, applicable, get_config, list_archs
 from ..core import compat
-from ..configs.base import ModelConfig, ShapeConfig
 from ..core.dist import DistContext, use_dist
-from ..models.model import init_params, make_cache
-from ..optim.adamw import OptConfig, init_opt_state
+from ..models.model import make_cache
+from ..optim.adamw import OptConfig
 from ..roofline.analysis import (build_roofline, collective_bytes,
                                  model_flops_estimate)
 from ..roofline.perf_model import step_perf
@@ -36,6 +35,7 @@ from ..train.train_step import (make_prefill_step, make_serve_step,
 from .mesh import make_production_mesh
 from .sharding import (axis_size, batch_specs, cache_specs, dp_axes,
                        expert_axis, param_specs, to_shardings)
+from .specs import abstract_state, input_specs
 
 RESULTS = pathlib.Path(__file__).resolve().parents[3] / "results" / "dryrun"
 
@@ -74,35 +74,6 @@ VARIANTS: dict[str, dict] = {
                           "moe_ep_axis": "model",
                           "cfg": {"param_dtype": "bfloat16"}},
 }
-
-
-def input_specs(cfg: ModelConfig, shape: ShapeConfig) -> dict:
-    """Abstract (ShapeDtypeStruct) stand-ins for every model input."""
-    b, s = shape.global_batch, shape.seq_len
-    sds = jax.ShapeDtypeStruct
-    if shape.kind == "train":
-        batch = {
-            "tokens": sds((b, s), jnp.int32),
-            "labels": sds((b, s), jnp.int32),
-            "loss_mask": sds((b, s), jnp.float32),
-        }
-    elif shape.kind == "prefill":
-        batch = {"tokens": sds((b, s), jnp.int32)}
-    else:  # decode: one new token against a cache of seq_len
-        batch = {"tokens": sds((b, 1), jnp.int32)}
-    if cfg.family == "encdec":
-        batch["frames"] = sds((b, cfg.encoder_seq, cfg.d_model), jnp.bfloat16)
-    if cfg.num_patches:
-        batch["patch_embeds"] = sds((b, cfg.num_patches, cfg.d_model),
-                                    jnp.bfloat16)
-    return batch
-
-
-def abstract_state(cfg: ModelConfig, shape: ShapeConfig, with_opt: bool):
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    params = jax.eval_shape(lambda k: init_params(cfg, k), key)
-    opt = jax.eval_shape(init_opt_state, params) if with_opt else None
-    return params, opt
 
 
 def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,

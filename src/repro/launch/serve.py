@@ -20,6 +20,7 @@ import numpy as np
 from ..configs import get_config
 from ..core.gemm import autotune, epilogue_stats, plan_mode_stats
 from ..models.model import init_params
+from ..runtime.compile_cache import enable_compile_cache
 from ..serve.engine import Request, ServeEngine
 
 _DEFAULT_CACHE = pathlib.Path(__file__).resolve().parents[3] \
@@ -83,6 +84,7 @@ def main() -> None:
                          "against it once calibrated")
     args = ap.parse_args()
 
+    enable_compile_cache()
     load_plan_cache(args.plan_cache)
     cfg = get_config(args.arch)
     params = init_params(cfg, jax.random.PRNGKey(0))

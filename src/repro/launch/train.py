@@ -50,11 +50,13 @@ def main() -> None:
     from ..configs import get_config
     from ..configs.base import ShapeConfig
     from ..optim.adamw import OptConfig
+    from ..runtime.compile_cache import enable_compile_cache
     from ..train.trainer import Trainer
     from .mesh import make_mesh
     from .sharding import (batch_specs, param_specs, to_shardings)
-    from .dryrun import abstract_state, input_specs
+    from .specs import abstract_state, input_specs
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     shape = ShapeConfig("cli", seq_len=args.seq, global_batch=args.batch,
                         kind="train")

@@ -65,8 +65,8 @@ KNOWN_SITES = frozenset({
 
 
 class ChaosError(RuntimeError):
-    """Base class for injected faults (tells handlers the failure is
-    synthetic; real exceptions take the same degradation paths)."""
+    """Base class for injected faults.  The dispatch and EP fallback
+    ladders catch only this class: a real exception propagates."""
 
 
 class KernelLaunchFailure(ChaosError):

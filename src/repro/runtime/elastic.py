@@ -78,7 +78,7 @@ class ElasticRunner:
         self.metrics_log: list[dict] = []
 
     def _shardings(self, mesh) -> dict:
-        from ..launch.dryrun import abstract_state, input_specs
+        from ..launch.specs import abstract_state, input_specs
         from ..launch.sharding import batch_specs, param_specs, to_shardings
         params_s, opt_s = abstract_state(self.cfg, self.shape, with_opt=True)
         batch_s = input_specs(self.cfg, self.shape)

@@ -8,7 +8,8 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-# Kernel tests run in interpret mode on CPU.
+# Model-level GEMMs run on XLA here; kernel tests ask for the Pallas
+# interpreter explicitly (backend="pallas_interpret" / interpret=True).
 os.environ.setdefault("REPRO_GEMM_BACKEND", "xla")
 
 

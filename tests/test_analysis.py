@@ -110,6 +110,49 @@ def test_mutation_over_budget_accumulator(base_plan):
         contracts.check_plan("dense", (4096, 4096, 4096), p))
 
 
+def test_mutation_tn_bm_on_lane_axis(base_plan):
+    # "tn" blocks A as (bk, bm): bm is a lane extent there, so the
+    # sublane-sized bm a small-M "nn" plan carries is refused by Mosaic.
+    p = dataclasses.replace(base_plan, bm=16, bn=128, bk=2048)
+    dims = (8, 2048, 6144)
+    kw = dict(in_bytes=2, out_bytes=2)
+    assert "misaligned_block" not in _codes(
+        contracts.check_plan("dense", dims, p, **kw))
+    assert "misaligned_block" in _codes(
+        contracts.check_plan("dense", dims, p, trans="tn", **kw))
+    assert "misaligned_block" in _codes(
+        contracts.check_plan("batched", (4,) + dims, p, trans="tn", **kw))
+    # The ragged dW blocks x as (bk, bm): D (bm) is its lane axis.
+    assert "misaligned_block" in _codes(contracts.check_blocks(
+        "ragged", (8, 1024, 2048, 512), bm=16, bn=128, bk=128, ragged="k"))
+    # The planners emit lane-aligned bm wherever M is the minor axis.
+    assert tuner.plan_gemm(8, 2048, 6144, 2, 2, trans="tn").bm % 128 == 0
+    assert tuner.plan_batched_gemm(4, 8, 2048, 6144, 2, 2,
+                                   trans="tn").bm % 128 == 0
+    assert tuner.plan_ragged_gemm(8, 1024, 16, 512, 2, 2,
+                                  ragged="k").bm % 128 == 0
+
+
+def test_mutation_vmem_extras_over_budget(base_plan):
+    # Base blocks fit; the residual block + bias row push the working set
+    # past the budget.  That plan is an error now, not a warning.
+    p = dataclasses.replace(base_plan, bm=1024, bn=1024, bk=512)
+    epi = Epilogue(bias=True, residual=True)
+    kw = dict(bm=1024, bn=1024, bk=512, in_bytes=2, out_bytes=2)
+    assert contracts.vmem_footprint("dense", **kw) <= TPU_V5E.vmem_budget
+    assert contracts.vmem_footprint("dense", epilogue=epi,
+                                    **kw) > TPU_V5E.vmem_budget
+    vs = contracts.check_plan("dense", (4096, 4096, 4096), p, in_bytes=2,
+                              out_bytes=2, epilogue=epi)
+    assert "vmem_budget_extras" in _codes(vs)
+    # The planner admits on the same full footprint.
+    plan = tuner.plan_gemm(512, 6144, 2048, 2, 2, epi_ops=epi.num_ops,
+                           epilogue=epi)
+    assert not contracts.errors(contracts.check_plan(
+        "dense", (512, 6144, 2048), plan, in_bytes=2, out_bytes=2,
+        epilogue=epi))
+
+
 def test_mutation_splitk_nonlinear_epilogue(base_plan):
     p = dataclasses.replace(base_plan, nsplit=2, bk=128, fuse=True)
     vs = contracts.check_plan("dense", (4096, 4096, 4096), p,
@@ -328,15 +371,16 @@ def test_ragged_wrappers_skip_pad_when_aligned(monkeypatch):
     x = jnp.ones((384, 256), jnp.float32)
     w = jnp.ones((3, 256, 384), jnp.float32)
     off = jnp.asarray([0, 128, 200, 384], jnp.int32)
-    y = ops.ragged_gemm(x, w, off, bm=64, bn=128, bk=128)
+    y = ops.ragged_gemm(x, w, off, bm=64, bn=128, bk=128, interpret=True)
     assert calls == [] and y.shape == (384, 384)
     dw = ops.ragged_gemm_dw(x, jnp.ones((384, 128), jnp.float32), off,
-                            bm=128, bn=128, bk=64)
+                            bm=128, bn=128, bk=64, interpret=True)
     assert calls == [] and dw.shape == (3, 256, 128)
     # Unaligned rows still pad (and still compute correctly).
     xu = jnp.ones((250, 256), jnp.float32)
     offu = jnp.asarray([0, 128, 200, 250], jnp.int32)
-    yu = ops.ragged_gemm(xu, w, offu, bm=64, bn=128, bk=128)
+    yu = ops.ragged_gemm(xu, w, offu, bm=64, bn=128, bk=128,
+                         interpret=True)
     assert calls and yu.shape == (250, 384)
     np.testing.assert_allclose(np.asarray(yu), 256.0)
 
@@ -349,7 +393,7 @@ def test_ragged_aligned_matches_unaligned_numerics(rng_key):
     x = jax.random.normal(k1, (256, 128), jnp.float32)
     w = jax.random.normal(k2, (4, 128, 256), jnp.float32)
     off = jnp.asarray([0, 64, 100, 200, 256], jnp.int32)
-    y = ops.ragged_gemm(x, w, off, bm=64, bn=128, bk=128)
+    y = ops.ragged_gemm(x, w, off, bm=64, bn=128, bk=128, interpret=True)
     bounds = np.asarray(off)
     ref = np.concatenate([
         np.asarray(x)[s:e] @ np.asarray(w)[i]

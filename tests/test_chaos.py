@@ -182,6 +182,46 @@ def test_ladder_counts_once_per_fault_and_warns_once():
     assert len(ladder) == 1    # first occurrence logged, repeats silent
 
 
+def _boom(*_args, **_kwargs):
+    raise RuntimeError("Mosaic refused the kernel")
+
+
+@pytest.mark.parametrize("kernel,call", [
+    ("gemm", lambda: matmul(_rng(11, 40, 16), _rng(12, 16, 20),
+                            backend="pallas_interpret")),
+    ("batched_gemm", lambda: batched_matmul(
+        _rng(13, 3, 40, 16), _rng(14, 3, 16, 20),
+        backend="pallas_interpret")),
+    ("ragged_gemm", lambda: ragged_matmul(
+        _rng(15, 40, 16), _rng(16, 2, 16, 20),
+        jnp.asarray([0, 17, 40], jnp.int32), backend="pallas_interpret")),
+    ("ragged_gemm_swiglu", lambda: ragged_swiglu(
+        _rng(17, 40, 16), _rng(18, 2, 16, 20), _rng(19, 2, 16, 20),
+        jnp.asarray([0, 17, 40], jnp.int32), backend="pallas_interpret")),
+])
+def test_ladder_real_kernel_error_propagates(monkeypatch, kernel, call):
+    """Only injected faults take the ladder: a real kernel error (a Mosaic
+    refusal, a lowering bug) reaches the caller, and nothing is counted as
+    a degraded serving."""
+    clear_plan_cache()
+    monkeypatch.setattr(_dispatch._ops, kernel, _boom)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        call()
+    assert "degraded" not in plan_mode_stats()
+
+
+def test_ep_ladder_real_error_propagates():
+    from repro.core.gemm import distributed
+    clear_plan_cache()
+
+    def run(schedule):
+        raise ValueError(f"{schedule} executor failed to lower")
+
+    with pytest.raises(ValueError, match="ring executor"):
+        distributed._ep_ladder(run, "ring", lambda: None)
+    assert "degraded" not in plan_mode_stats()
+
+
 # -------------------- stale-shard plans after a re-mesh --------------------
 
 def test_stale_shard_cached_plans_not_served():
