@@ -286,100 +286,115 @@ def attention(
       per-row position masks (positions past a slot's depth never attend).
     """
     b, s, _ = x.shape
-    q = dense(x, params["wq"], compute_dtype).reshape(b, s, num_heads, head_dim)
-    # (S,) positions broadcast over the batch; (B, S) are per-row (vector
-    # cache_index decode) and feed rope directly.
-    pos2 = positions if positions.ndim == 2 else positions[None, :]
+    with jax.named_scope("qkv"):
+        q = dense(x, params["wq"], compute_dtype).reshape(
+            b, s, num_heads, head_dim)
+        # (S,) positions broadcast over the batch; (B, S) are per-row
+        # (vector cache_index decode) and feed rope directly.
+        pos2 = positions if positions.ndim == 2 else positions[None, :]
+        k = v = None                       # cross-attention: precomputed
+        if cross_kv is None:
+            k = dense(x, params["wk"], compute_dtype).reshape(
+                b, s, num_kv_heads, head_dim)
+            v = dense(x, params["wv"], compute_dtype).reshape(
+                b, s, num_kv_heads, head_dim)
+        if qk_norm:
+            q = rms_norm(q, params["q_norm"])
+            if k is not None:
+                k = rms_norm(k, params["k_norm"])
+        if use_rope:
+            q = rope(q, pos2, rope_theta)
+            if k is not None:
+                k = rope(k, pos2, rope_theta)
+    with jax.named_scope("core"):
+        out, new_cache = _attend(
+            q, k, v, positions=positions, window=window, causal=causal,
+            kv_cache=kv_cache, cache_index=cache_index, cross_kv=cross_kv,
+            block_kv=block_kv, unroll=unroll, page_table=page_table)
+        out = out.reshape(b, s, num_heads * head_dim)
+    with jax.named_scope("out"):
+        return dense(out, params["wo"], compute_dtype,
+                     residual=residual), new_cache
 
+
+def _attend(q, k, v, *, positions, window, causal, kv_cache, cache_index,
+            cross_kv, block_kv, unroll, page_table):
+    """The attention core of ``attention`` on projected (and roped) q, k, v:
+    cache write, then softmax attention over the keys in reach.  Returns
+    (out (B, S, H, D), new_kv_cache | None)."""
+    b, s, _, head_dim = q.shape
     if cross_kv is not None:
         k, v = cross_kv
         kv_pos = jnp.arange(k.shape[1])
-        if qk_norm:
-            q = rms_norm(q, params["q_norm"])
-        if use_rope:
-            q = rope(q, pos2, rope_theta)
         out = blockwise_attention(
             q, k, v, q_positions=positions, kv_positions=kv_pos,
             window=0, causal=False, block_kv=block_kv, unroll=unroll)
-        new_cache = None
+        return out, None
+    num_kv_heads = k.shape[2]
+    if kv_cache is not None and page_table is not None:
+        # Paged single-token decode: scatter the new K/V at the slot's
+        # physical row, gather the logical per-slot view, run the
+        # per-row-masked decode attention over it.
+        ck, cv = kv_cache                  # (num_pages, page, KVH, D)
+        assert cache_index is not None and s == 1
+        idx = jnp.asarray(cache_index)
+        nump, page = ck.shape[0], ck.shape[1]
+        phys = (page_table[jnp.arange(b), idx // page] * page
+                + idx % page)
+        flat_k = ck.reshape(nump * page, num_kv_heads, head_dim)
+        flat_v = cv.reshape(nump * page, num_kv_heads, head_dim)
+        flat_k = flat_k.at[phys].set(k[:, 0].astype(flat_k.dtype))
+        flat_v = flat_v.at[phys].set(v[:, 0].astype(flat_v.dtype))
+
+        def view(flat):
+            paged = flat.reshape(nump, page, num_kv_heads, head_dim)
+            return paged[page_table].reshape(
+                b, -1, num_kv_heads, head_dim)
+
+        out = decode_attention(q, view(flat_k), view(flat_v),
+                               q_pos=idx, window=window)
+        return out, (flat_k.reshape(ck.shape), flat_v.reshape(cv.shape))
+    if kv_cache is None:
+        out = blockwise_attention(
+            q, k, v, q_positions=positions, kv_positions=positions,
+            window=window, causal=causal, block_kv=block_kv, unroll=unroll)
+        return out, None
+    ck, cv = kv_cache
+    assert cache_index is not None
+    idx = jnp.asarray(cache_index)
+    if idx.ndim:
+        # Per-row insert: slot b's token lands at ITS depth idx[b],
+        # not at the batch max.
+        upd = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
+            c, u, (i, 0, 0)))
+        ck = upd(ck, k.astype(ck.dtype), idx)
+        cv = upd(cv, v.astype(cv.dtype), idx)
     else:
-        k = dense(x, params["wk"], compute_dtype).reshape(b, s, num_kv_heads, head_dim)
-        v = dense(x, params["wv"], compute_dtype).reshape(b, s, num_kv_heads, head_dim)
-        if qk_norm:
-            q = rms_norm(q, params["q_norm"])
-            k = rms_norm(k, params["k_norm"])
-        if use_rope:
-            q = rope(q, pos2, rope_theta)
-            k = rope(k, pos2, rope_theta)
-        if kv_cache is not None and page_table is not None:
-            # Paged single-token decode: scatter the new K/V at the slot's
-            # physical row, gather the logical per-slot view, run the
-            # per-row-masked decode attention over it.
-            ck, cv = kv_cache                  # (num_pages, page, KVH, D)
-            assert cache_index is not None and s == 1
-            idx = jnp.asarray(cache_index)
-            nump, page = ck.shape[0], ck.shape[1]
-            phys = (page_table[jnp.arange(b), idx // page] * page
-                    + idx % page)
-            flat_k = ck.reshape(nump * page, num_kv_heads, head_dim)
-            flat_v = cv.reshape(nump * page, num_kv_heads, head_dim)
-            flat_k = flat_k.at[phys].set(k[:, 0].astype(flat_k.dtype))
-            flat_v = flat_v.at[phys].set(v[:, 0].astype(flat_v.dtype))
-
-            def view(flat):
-                paged = flat.reshape(nump, page, num_kv_heads, head_dim)
-                return paged[page_table].reshape(
-                    b, -1, num_kv_heads, head_dim)
-
-            out = decode_attention(q, view(flat_k), view(flat_v),
-                                   q_pos=idx, window=window)
-            new_cache = (flat_k.reshape(ck.shape), flat_v.reshape(cv.shape))
-        elif kv_cache is not None:
-            ck, cv = kv_cache
-            assert cache_index is not None
-            idx = jnp.asarray(cache_index)
-            if idx.ndim:
-                # Per-row insert: slot b's token lands at ITS depth idx[b],
-                # not at the batch max.
-                upd = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
-                    c, u, (i, 0, 0)))
-                ck = upd(ck, k.astype(ck.dtype), idx)
-                cv = upd(cv, v.astype(cv.dtype), idx)
-            else:
-                ck = jax.lax.dynamic_update_slice(
-                    ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
-                cv = jax.lax.dynamic_update_slice(
-                    cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
-            dist = current_dist()
-            if s > 1:
-                # Prefill from an empty cache: the freshly computed K/V span
-                # the whole valid range, so attend over them directly (keeps
-                # the scan over KV blocks off the sharded cache buffer).
-                out = blockwise_attention(
-                    q, k, v, q_positions=positions, kv_positions=positions,
-                    window=window, causal=causal, block_kv=block_kv, unroll=unroll)
-            elif idx.ndim:
-                # Mixed-depth fused decode: per-row masks from the (B,)
-                # positions.
-                out = decode_attention(q, ck, cv, q_pos=idx, window=window)
-            elif dist is not None and dist.sp_decode and dist.model_size > 1:
-                # K-parallel decode across chips (paper Alg. 5).
-                out = flash_decode(q, ck, cv, pos=cache_index + s - 1,
-                                   window=window, dist=dist)
-            else:
-                kv_pos = jnp.arange(ck.shape[1])
-                out = blockwise_attention(
-                    q, ck, cv, q_positions=positions, kv_positions=kv_pos,
-                    window=window, causal=causal,
-                    kv_valid_len=cache_index + s, block_kv=block_kv,
-                    unroll=unroll)
-            new_cache = (ck, cv)
-        else:
-            out = blockwise_attention(
-                q, k, v, q_positions=positions, kv_positions=positions,
-                window=window, causal=causal, block_kv=block_kv, unroll=unroll)
-            new_cache = None
-
-    out = out.reshape(b, s, num_heads * head_dim)
-    return dense(out, params["wo"], compute_dtype,
-                 residual=residual), new_cache
+        ck = jax.lax.dynamic_update_slice(
+            ck, k.astype(ck.dtype), (0, cache_index, 0, 0))
+        cv = jax.lax.dynamic_update_slice(
+            cv, v.astype(cv.dtype), (0, cache_index, 0, 0))
+    dist = current_dist()
+    if s > 1:
+        # Prefill from an empty cache: the freshly computed K/V span
+        # the whole valid range, so attend over them directly (keeps
+        # the scan over KV blocks off the sharded cache buffer).
+        out = blockwise_attention(
+            q, k, v, q_positions=positions, kv_positions=positions,
+            window=window, causal=causal, block_kv=block_kv, unroll=unroll)
+    elif idx.ndim:
+        # Mixed-depth fused decode: per-row masks from the (B,)
+        # positions.
+        out = decode_attention(q, ck, cv, q_pos=idx, window=window)
+    elif dist is not None and dist.sp_decode and dist.model_size > 1:
+        # K-parallel decode across chips (paper Alg. 5).
+        out = flash_decode(q, ck, cv, pos=cache_index + s - 1,
+                           window=window, dist=dist)
+    else:
+        kv_pos = jnp.arange(ck.shape[1])
+        out = blockwise_attention(
+            q, ck, cv, q_positions=positions, kv_positions=kv_pos,
+            window=window, causal=causal,
+            kv_valid_len=cache_index + s, block_kv=block_kv,
+            unroll=unroll)
+    return out, (ck, cv)

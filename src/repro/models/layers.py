@@ -3,7 +3,8 @@
 All dense contractions route through ``repro.core.gemm.project`` so the
 ftIMM planner sees every GEMM in the framework (and dispatches to the Pallas
 kernels on TPU).  Weights are kept in ``param_dtype`` (fp32 master) and cast
-to ``compute_dtype`` at use.
+to ``compute_dtype`` at use, each cast under a ``cast`` named scope so the
+device time it takes reads apart in a profile.
 
 Elementwise layer tails fuse into their producing GEMM: ``dense`` takes
 optional ``bias`` / ``residual`` / ``activation`` (an ``Epilogue`` applied at
@@ -34,13 +35,14 @@ def dense(x: jax.Array, w: jax.Array, compute_dtype=jnp.bfloat16, *,
     backward."""
     epi = Epilogue(bias=bias is not None, activation=activation,
                    residual=residual is not None)
+    x = x.astype(compute_dtype)
+    with jax.named_scope("cast"):
+        w = w.astype(compute_dtype)
+        bias = None if bias is None else bias.astype(compute_dtype)
     if epi.is_identity:
-        return project(x.astype(compute_dtype), w.astype(compute_dtype),
-                       out_dtype=compute_dtype, quant=quant)
+        return project(x, w, out_dtype=compute_dtype, quant=quant)
     return project(
-        x.astype(compute_dtype), w.astype(compute_dtype),
-        out_dtype=compute_dtype, epilogue=epi,
-        bias=None if bias is None else bias.astype(compute_dtype),
+        x, w, out_dtype=compute_dtype, epilogue=epi, bias=bias,
         residual=None if residual is None
         else residual.astype(compute_dtype), quant=quant)
 
@@ -72,9 +74,11 @@ def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
     kernel launch (x streamed once against both panels, silu(gate)*up at the
     accumulator flush); the residual add fuses into the down projection's
     epilogue instead of a separate pass over the layer output."""
-    h = project_swiglu(x.astype(compute_dtype),
-                       w_gate.astype(compute_dtype),
-                       w_up.astype(compute_dtype), out_dtype=compute_dtype)
+    x = x.astype(compute_dtype)
+    with jax.named_scope("cast"):
+        w_gate = w_gate.astype(compute_dtype)
+        w_up = w_up.astype(compute_dtype)
+    h = project_swiglu(x, w_gate, w_up, out_dtype=compute_dtype)
     return dense(h, w_down, compute_dtype, residual=residual)
 
 
@@ -94,7 +98,9 @@ def rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0) -> jax.Arra
 
 def embed(tokens: jax.Array, table: jax.Array,
           compute_dtype=jnp.bfloat16) -> jax.Array:
-    return table.astype(compute_dtype)[tokens]
+    with jax.named_scope("cast"):
+        table = table.astype(compute_dtype)
+    return table[tokens]
 
 
 def unembed(x: jax.Array, table: jax.Array, vocab_size: int,
@@ -104,7 +110,9 @@ def unembed(x: jax.Array, table: jax.Array, vocab_size: int,
     The table arrives (vocab/model, d_model/dp)-sharded (ZeRO-3); constrain
     the transposed operand to (None, model) so GSPMD all-gathers the small
     D dim instead of all-reducing a (tokens x vocab) partial product."""
-    wt = shard_act(table.astype(compute_dtype).T, None, "model")
+    with jax.named_scope("cast"):
+        wt = table.astype(compute_dtype).T
+    wt = shard_act(wt, None, "model")
     logits = project(x.astype(compute_dtype), wt, out_dtype=jnp.float32)
     pad = logits.shape[-1] - vocab_size
     if pad > 0:

@@ -34,14 +34,27 @@ def init_params(cfg: ModelConfig, key) -> dict:
 def _embed_inputs(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array]:
     """Token (+frontend) embeddings and positions. Returns (h, positions)."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    h = embed(batch["tokens"], params["embed"], cdt)
-    if cfg.num_patches and "patch_embeds" in batch:
-        patches = dense(batch["patch_embeds"].astype(cdt),
-                        params["patch_proj"], cdt)
-        h = jnp.concatenate([patches, h], axis=1)
+    with jax.named_scope("embed"):
+        h = embed(batch["tokens"], params["embed"], cdt)
+        if cfg.num_patches and "patch_embeds" in batch:
+            patches = dense(batch["patch_embeds"].astype(cdt),
+                            params["patch_proj"], cdt)
+            h = jnp.concatenate([patches, h], axis=1)
     h = shard_act(h, "dp", None, None)
     positions = jnp.arange(h.shape[1])
     return h, positions
+
+
+def _final_norm(h: jax.Array, params) -> jax.Array:
+    with jax.named_scope("final_norm"):
+        return rms_norm(h, params["final_norm"])
+
+
+def _lm_head(h: jax.Array, params, cfg: ModelConfig) -> jax.Array:
+    """Logits over the tied embedding table."""
+    with jax.named_scope("lm_head"):
+        return unembed(h, params["embed"], cfg.vocab_size,
+                       jnp.dtype(cfg.compute_dtype))
 
 
 def encode(params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
@@ -87,11 +100,10 @@ def forward_train(params, cfg: ModelConfig, batch) -> tuple[jax.Array, jax.Array
         h, aux = stack_train(params, cfg, h, positions, cross_kv_stack=cross)
     else:
         h, aux = stack_train(params, cfg, h, positions)
-    h = rms_norm(h, params["final_norm"])
+    h = _final_norm(h, params)
     if cfg.num_patches:
         h = h[:, cfg.num_patches:]        # logits over text positions only
-    logits = unembed(h, params["embed"], cfg.vocab_size,
-                     jnp.dtype(cfg.compute_dtype))
+    logits = _lm_head(h, params, cfg)
     logits = shard_act(logits, "dp", None, "model")
     return logits, aux
 
@@ -138,10 +150,8 @@ def prefill(params, cfg: ModelConfig, batch, cache) -> tuple[jax.Array, dict]:
         cache.update({"cross_k": ck, "cross_v": cv})
     h, new_cache, _ = stack_cached(params, cfg, h, positions, cache,
                                    cache_index=jnp.int32(0))
-    h = rms_norm(h[:, -1:], params["final_norm"])
-    logits = unembed(h, params["embed"], cfg.vocab_size,
-                     jnp.dtype(cfg.compute_dtype))
-    return logits[:, 0], new_cache
+    h = _final_norm(h[:, -1:], params)
+    return _lm_head(h, params, cfg)[:, 0], new_cache
 
 
 def prefill_bucket(params, cfg: ModelConfig, batch, cache,
@@ -163,10 +173,8 @@ def prefill_bucket(params, cfg: ModelConfig, batch, cache,
     extra = cfg.num_patches or 0
     idx = jnp.asarray(lens, jnp.int32) - 1 + extra       # (B,)
     last = jnp.take_along_axis(h, idx[:, None, None], axis=1)
-    last = rms_norm(last, params["final_norm"])
-    logits = unembed(last, params["embed"], cfg.vocab_size,
-                     jnp.dtype(cfg.compute_dtype))
-    return logits[:, 0], new_cache
+    last = _final_norm(last, params)
+    return _lm_head(last, params, cfg)[:, 0], new_cache
 
 
 def decode_step(params, cfg: ModelConfig, tokens: jax.Array, cache: dict,
@@ -179,12 +187,11 @@ def decode_step(params, cfg: ModelConfig, tokens: jax.Array, cache: dict,
     ``page_table`` (B, max_pages): ``cache`` holds paged KV pools shared by
     every slot (see ``serve.kv_pages``) instead of per-slot dense buffers.
     Returns (logits (B, V), new cache)."""
-    cdt = jnp.dtype(cfg.compute_dtype)
-    h = embed(tokens, params["embed"], cdt)
+    with jax.named_scope("embed"):
+        h = embed(tokens, params["embed"], jnp.dtype(cfg.compute_dtype))
     pos = jnp.asarray(pos)
     positions = pos[:, None] if pos.ndim else pos + jnp.arange(1)
     h, new_cache, _ = stack_cached(params, cfg, h, positions, cache,
                                    cache_index=pos, page_table=page_table)
-    h = rms_norm(h, params["final_norm"])
-    logits = unembed(h, params["embed"], cfg.vocab_size, cdt)
-    return logits[:, 0], new_cache
+    h = _final_norm(h, params)
+    return _lm_head(h, params, cfg)[:, 0], new_cache

@@ -107,17 +107,26 @@ def capacity(num_tokens: int, num_experts: int, top_k: int,
 
 def _router(x: jax.Array, params: dict, num_experts: int, top_k: int):
     """Shared router head: T1 GEMM + top-k gates + Switch-style aux loss."""
-    logits = project(x, params["router"].astype(x.dtype),
-                     out_dtype=jnp.float32)                      # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_w, gate_idx = jax.lax.top_k(probs, top_k)               # (T, K)
-    if top_k > 1:
-        gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
-    me = jnp.mean(probs, axis=0)
-    one_hot = jax.nn.one_hot(gate_idx[:, 0], num_experts)
-    ce = jnp.mean(one_hot, axis=0)
-    aux = num_experts * jnp.sum(me * ce)
+    with jax.named_scope("router"):
+        with jax.named_scope("cast"):
+            w = params["router"].astype(x.dtype)
+        logits = project(x, w, out_dtype=jnp.float32)            # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_w, gate_idx = jax.lax.top_k(probs, top_k)           # (T, K)
+        if top_k > 1:
+            gate_w = gate_w / jnp.sum(gate_w, axis=-1, keepdims=True)
+        me = jnp.mean(probs, axis=0)
+        one_hot = jax.nn.one_hot(gate_idx[:, 0], num_experts)
+        ce = jnp.mean(one_hot, axis=0)
+        aux = num_experts * jnp.sum(me * ce)
     return gate_w, gate_idx, aux
+
+
+def _expert_weights(params: dict, compute_dtype):
+    """The gate, up and down expert panels in the compute dtype."""
+    with jax.named_scope("cast"):
+        return tuple(params[k].astype(compute_dtype)
+                     for k in ("w_gate", "w_up", "w_down"))
 
 
 def moe_mlp(
@@ -158,26 +167,28 @@ def moe_mlp(
 
     gate_w, gate_idx, aux = _router(xc, params, e, top_k)
 
-    # Position of each (token, k) within its expert's capacity bucket.
-    flat_idx = gate_idx.reshape(-1)                              # (T*K,)
-    sel = jax.nn.one_hot(flat_idx, e, dtype=jnp.int32)           # (T*K, E)
-    pos_in_e = jnp.cumsum(sel, axis=0) - 1                       # rank within expert
-    pos = jnp.take_along_axis(pos_in_e, flat_idx[:, None], axis=1)[:, 0]
-    keep = pos < c
-    slot = jnp.where(keep, flat_idx * c + pos, e * c)            # drop -> OOB
+    with jax.named_scope("dispatch"):
+        # Position of each (token, k) within its expert's capacity bucket.
+        flat_idx = gate_idx.reshape(-1)                          # (T*K,)
+        sel = jax.nn.one_hot(flat_idx, e, dtype=jnp.int32)       # (T*K, E)
+        pos_in_e = jnp.cumsum(sel, axis=0) - 1          # rank within expert
+        pos = jnp.take_along_axis(pos_in_e, flat_idx[:, None],
+                                  axis=1)[:, 0]
+        keep = pos < c
+        slot = jnp.where(keep, flat_idx * c + pos, e * c)        # drop -> OOB
 
-    # Scatter-pack tokens into the (E*C, D) buffer (paper: each "core"
-    # receives its private A panel).
-    tok_idx = jnp.repeat(jnp.arange(t), top_k)
-    buf = jnp.zeros((e * c, d), compute_dtype)
-    buf = buf.at[slot].add(xc[tok_idx], mode="drop")
-    buf = buf.reshape(e, c, d)
-    ctx = current_dist()
-    if ctx is not None and ctx.moe_buf_shard:
-        # dispatch buffers replicated by default (GSPMD scatter inference);
-        # shard capacity over dp — the paper's "each core owns its private
-        # A panel" at the MoE level
-        buf = shard_act(buf, None, "dp", None)
+        # Scatter-pack tokens into the (E*C, D) buffer (paper: each "core"
+        # receives its private A panel).
+        tok_idx = jnp.repeat(jnp.arange(t), top_k)
+        buf = jnp.zeros((e * c, d), compute_dtype)
+        buf = buf.at[slot].add(xc[tok_idx], mode="drop")
+        buf = buf.reshape(e, c, d)
+        ctx = current_dist()
+        if ctx is not None and ctx.moe_buf_shard:
+            # dispatch buffers replicated by default (GSPMD scatter
+            # inference); shard capacity over dp — the paper's "each core
+            # owns its private A panel" at the MoE level
+            buf = shard_act(buf, None, "dp", None)
 
     # Expert GEMMs (T3 per shard): grouped ftIMM GEMMs (E, C, D) @ (E, D, F)
     # through the CMR planner — the batch dim is the expert index, the
@@ -185,16 +196,17 @@ def moe_mlp(
     # their backward dW is the T2-shaped grouped GEMM, planned the same way.
     # The gate/up pair is ONE fused silu(gate)*up launch (the capacity-mode
     # analogue of the ragged path's fused SwiGLU).
-    wg = params["w_gate"].astype(compute_dtype)
-    wu = params["w_up"].astype(compute_dtype)
-    wd = params["w_down"].astype(compute_dtype)
-    h = grouped_swiglu(buf, wg, wu)
-    y_buf = grouped_matmul(h, wd).reshape(e * c, d)
+    with jax.named_scope("experts"):
+        wg, wu, wd = _expert_weights(params, compute_dtype)
+        h = grouped_swiglu(buf, wg, wu)
+        y_buf = grouped_matmul(h, wd).reshape(e * c, d)
 
-    # Gather back and combine with gate weights.
-    y_tok = jnp.take(y_buf, jnp.minimum(slot, e * c - 1), axis=0)
-    y_tok = y_tok * (keep * gate_w.reshape(-1))[:, None].astype(compute_dtype)
-    y = jnp.sum(y_tok.reshape(t, top_k, d), axis=1)
+    with jax.named_scope("combine"):
+        # Gather back and combine with gate weights.
+        y_tok = jnp.take(y_buf, jnp.minimum(slot, e * c - 1), axis=0)
+        y_tok = y_tok * (keep * gate_w.reshape(-1))[:, None].astype(
+            compute_dtype)
+        y = jnp.sum(y_tok.reshape(t, top_k, d), axis=1)
     return y.astype(x.dtype), aux
 
 
@@ -230,14 +242,15 @@ def _moe_mlp_ragged(
 
     # Sort the (T*K,) routed copies by expert id (stable: ties keep token
     # order) and build the per-expert prefix sums — the dynamic group sizes.
-    flat_idx = gate_idx.reshape(-1)                              # (T*K,)
-    order = jnp.argsort(flat_idx)                                # stable
-    tok_sorted = order // top_k                                  # token of slot
-    counts = jnp.zeros((e,), jnp.int32).at[flat_idx].add(1)
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)]).astype(jnp.int32)
-
-    xs = jnp.take(xc, tok_sorted, axis=0)                        # (T*K, D)
+    with jax.named_scope("dispatch"):
+        flat_idx = gate_idx.reshape(-1)                          # (T*K,)
+        order = jnp.argsort(flat_idx)                            # stable
+        tok_sorted = order // top_k                      # token of slot
+        counts = jnp.zeros((e,), jnp.int32).at[flat_idx].add(1)
+        offsets = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)]
+        ).astype(jnp.int32)
+        xs = jnp.take(xc, tok_sorted, axis=0)                    # (T*K, D)
 
     # Ragged expert GEMMs through the CMR planner: fused gate/up, then down.
     # When the sharding layout exposes an expert axis on the mesh
@@ -246,29 +259,31 @@ def _moe_mlp_ragged(
     # ``offsets`` prefix sums), G/num_shards panels per shard, inverse
     # exchange on the way back — instead of every chip replicating every
     # expert panel.
-    wg = params["w_gate"].astype(compute_dtype)
-    wu = params["w_up"].astype(compute_dtype)
-    wd = params["w_down"].astype(compute_dtype)
-    mesh, ep_axis = _ep_axis(e)
-    if ep_axis is not None:
-        # Fused EP pipeline: one d_model-wide exchange each way; the
-        # (rows, d_ff) hidden stays on the shard owning the expert.
-        # (Quantized panels deliberately not routed here: the exchange
-        # moves activations, not panels, so quant buys no wire bytes.)
-        ys = ep_ragged_moe(xs, wg, wu, wd, offsets, mesh=mesh, axis=ep_axis)
-    elif qcfg is not None and not qcfg.is_noop:
-        hg = ragged_matmul(xs, wg, offsets, quant=qcfg,
-                           out_dtype=jnp.float32)                # (T*K, F)
-        hu = ragged_matmul(xs, wu, offsets, quant=qcfg,
-                           out_dtype=jnp.float32)
-        h = (jax.nn.silu(hg) * hu).astype(compute_dtype)
-        ys = ragged_matmul(h, wd, offsets, quant=qcfg)           # (T*K, D)
-    else:
-        h = ragged_swiglu(xs, wg, wu, offsets)                   # (T*K, F)
-        ys = ragged_matmul(h, wd, offsets)                       # (T*K, D)
+    with jax.named_scope("experts"):
+        wg, wu, wd = _expert_weights(params, compute_dtype)
+        mesh, ep_axis = _ep_axis(e)
+        if ep_axis is not None:
+            # Fused EP pipeline: one d_model-wide exchange each way; the
+            # (rows, d_ff) hidden stays on the shard owning the expert.
+            # (Quantized panels deliberately not routed here: the exchange
+            # moves activations, not panels, so quant buys no wire bytes.)
+            ys = ep_ragged_moe(xs, wg, wu, wd, offsets, mesh=mesh,
+                               axis=ep_axis)
+        elif qcfg is not None and not qcfg.is_noop:
+            hg = ragged_matmul(xs, wg, offsets, quant=qcfg,
+                               out_dtype=jnp.float32)            # (T*K, F)
+            hu = ragged_matmul(xs, wu, offsets, quant=qcfg,
+                               out_dtype=jnp.float32)
+            h = (jax.nn.silu(hg) * hu).astype(compute_dtype)
+            ys = ragged_matmul(h, wd, offsets, quant=qcfg)       # (T*K, D)
+        else:
+            h = ragged_swiglu(xs, wg, wu, offsets)               # (T*K, F)
+            ys = ragged_matmul(h, wd, offsets)                   # (T*K, D)
 
-    # Un-sort and combine with gate weights (every copy kept — no drops).
-    gw_sorted = jnp.take(gate_w.reshape(-1), order)
-    y = jnp.zeros((t, d), compute_dtype).at[tok_sorted].add(
-        ys * gw_sorted[:, None].astype(compute_dtype))
+    with jax.named_scope("combine"):
+        # Un-sort and combine with gate weights (every copy kept — no
+        # drops).
+        gw_sorted = jnp.take(gate_w.reshape(-1), order)
+        y = jnp.zeros((t, d), compute_dtype).at[tok_sorted].add(
+            ys * gw_sorted[:, None].astype(compute_dtype))
     return y.astype(x.dtype), aux

@@ -122,21 +122,22 @@ def init_lm_params(cfg: ModelConfig, key) -> dict:
 # ------------------------------ block fwd -------------------------------
 
 def _mlp_or_moe(h, p, cfg: ModelConfig):
-    x = _gathered(rms_norm(h, p["ln2"]), cfg)
-    if "moe" in p:
-        b, s, d = x.shape
-        y, aux = moe_mlp(x.reshape(b * s, d), p["moe"],
-                         num_experts=cfg.num_experts, top_k=cfg.top_k,
-                         capacity_factor=cfg.capacity_factor,
-                         compute_dtype=_cdt(cfg),
-                         dispatch=cfg.moe_dispatch,
-                         quant=getattr(cfg, "quant", "none"))
-        return h + y.reshape(b, s, d), aux
-    # Residual add fused into the down projection's epilogue (and the
-    # gate/up pair is one fused kernel launch inside swiglu).
-    return swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                  p["mlp"]["w_down"], _cdt(cfg),
-                  residual=h), jnp.float32(0.0)
+    with jax.named_scope("moe" if "moe" in p else "mlp"):
+        x = _gathered(rms_norm(h, p["ln2"]), cfg)
+        if "moe" in p:
+            b, s, d = x.shape
+            y, aux = moe_mlp(x.reshape(b * s, d), p["moe"],
+                             num_experts=cfg.num_experts, top_k=cfg.top_k,
+                             capacity_factor=cfg.capacity_factor,
+                             compute_dtype=_cdt(cfg),
+                             dispatch=cfg.moe_dispatch,
+                             quant=getattr(cfg, "quant", "none"))
+            return h + y.reshape(b, s, d), aux
+        # Residual add fused into the down projection's epilogue (and the
+        # gate/up pair is one fused kernel launch inside swiglu).
+        return swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
+                      p["mlp"]["w_down"], _cdt(cfg),
+                      residual=h), jnp.float32(0.0)
 
 
 def _gathered(x, cfg):
@@ -157,23 +158,27 @@ def dense_block(h, p, cfg: ModelConfig, *, positions, window,
     """Returns (h, new_kv, aux).  The residual adds around attention (and
     the MLP, see ``_mlp_or_moe``) ride the out-projections' fused epilogues
     instead of separate elementwise passes over the block output.
-    ``page_table`` switches decode to the paged KV pool (serve.kv_pages)."""
-    h, new_kv = attention(
-        _gathered(rms_norm(h, p["ln1"]), cfg), p["attn"],
-        num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-        head_dim=cfg.head_dim_, positions=positions, window=window,
-        causal=causal, qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
-        use_rope=use_rope, kv_cache=kv, cache_index=cache_index,
-        compute_dtype=_cdt(cfg), unroll=cfg.scan_unroll, residual=h,
-        page_table=page_table)
-    if cross_kv is not None:
-        h, _ = attention(
-            rms_norm(h, p["ln_cross"]), p["cross"],
+    ``page_table`` switches decode to the paged KV pool (serve.kv_pages).
+    Named scopes ``attn`` / ``cross_attn`` and ``mlp`` / ``moe`` mark each
+    sub-block's ops in the compiled program's metadata."""
+    with jax.named_scope("attn"):
+        h, new_kv = attention(
+            _gathered(rms_norm(h, p["ln1"]), cfg), p["attn"],
             num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
-            head_dim=cfg.head_dim_, positions=positions, window=0,
-            causal=False, qk_norm=False, rope_theta=cfg.rope_theta,
-            use_rope=False, cross_kv=cross_kv, compute_dtype=_cdt(cfg),
-            unroll=cfg.scan_unroll, residual=h)
+            head_dim=cfg.head_dim_, positions=positions, window=window,
+            causal=causal, qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta,
+            use_rope=use_rope, kv_cache=kv, cache_index=cache_index,
+            compute_dtype=_cdt(cfg), unroll=cfg.scan_unroll, residual=h,
+            page_table=page_table)
+    if cross_kv is not None:
+        with jax.named_scope("cross_attn"):
+            h, _ = attention(
+                rms_norm(h, p["ln_cross"]), p["cross"],
+                num_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads,
+                head_dim=cfg.head_dim_, positions=positions, window=0,
+                causal=False, qk_norm=False, rope_theta=cfg.rope_theta,
+                use_rope=False, cross_kv=cross_kv, compute_dtype=_cdt(cfg),
+                unroll=cfg.scan_unroll, residual=h)
     h, aux = _mlp_or_moe(h, p, cfg)
     # Sequence parallelism on the residual stream (training): the layer-scan
     # carry is the dominant live activation (L x B x S x D saved for the

@@ -43,6 +43,14 @@ Overload safety (chaos-tested; see ``runtime.chaos``):
 
 Decode attention runs as flash-decode (paper K-parallel) whenever a
 DistContext is active — see models.attention.flash_decode.
+
+Profiler spans (``runtime.spans``; they record only under a profiler):
+``serve.step`` (``active``, ``queue``) holds ``serve.admit`` (``free``),
+which holds each ``serve.prefill`` (``bucket``, ``rows`` = slots x bucket,
+``tokens``, ``rids``), ``serve.insert`` (``rid``, ``slot``, ``len``) and
+``serve.sample`` (``rid``, ``slot``); then ``serve.decode`` (``active``,
+``pages_used``), ``serve.sync`` (the blocking read of the logits) and one
+``serve.sample`` per active slot.
 """
 from __future__ import annotations
 
@@ -61,6 +69,7 @@ from ..configs.base import ModelConfig
 from ..models.model import (decode_step, make_cache, prefill,
                             prefill_bucket)
 from ..runtime import chaos as _chaos
+from ..runtime.spans import span
 from .buckets import CostModel, bucket_for, make_buckets
 from .kv_pages import PageAllocator, PagedKV, PagesExhausted, pages_for
 
@@ -298,17 +307,26 @@ class ServeEngine:
         s = len(toks)
         fn = self._prefill_fn(s)
         one_cache = make_cache(self.cfg, 1, self.max_len)
-        logits, one_cache = fn(self.params,
-                               batch=self._frontend_batch(toks[None, :]),
-                               cache=one_cache)
+        with span("serve.prefill", bucket=s, rows=s, tokens=s,
+                  rids=[req.rid]):
+            logits, one_cache = fn(self.params,
+                                   batch=self._frontend_batch(toks[None, :]),
+                                   cache=one_cache)
         # copy slot cache in
         self.cache = jax.tree.map(
             lambda big, small: jax.lax.dynamic_update_slice_in_dim(
                 big, small.astype(big.dtype), slot, axis=1),
             self.cache, one_cache)
-        self._emit(req, self._sample(logits, req))
+        self._emit(req, self._sample_slot(slot, logits, req))
         self.pos[slot] = s + self.extra
         self.active[slot] = req
+
+    def _sample_slot(self, slot: int, logits, req: Request) -> int:
+        """``_sample`` for the request in ``slot``, inside its
+        ``serve.sample`` span: the upload of its logits row (host rows
+        arrive as numpy), the sampler, and the token's read-back."""
+        with span("serve.sample", rid=req.rid, slot=slot):
+            return self._sample(jnp.asarray(logits), req)
 
     def _sample(self, logits, req: Request) -> int:
         if req.temperature <= 0:
@@ -407,6 +425,11 @@ class ServeEngine:
     # --------------------------- admission -------------------------------
 
     def _admit(self) -> None:
+        with span("serve.admit",
+                  free=sum(r is None for r in self.active)):
+            self._admit_queued()
+
+    def _admit_queued(self) -> None:
         if not self.paged:
             for slot in range(self.b):
                 if self.active[slot] is None and self.queue:
@@ -449,17 +472,20 @@ class ServeEngine:
         fn = self._prefill_fn(len(toks))
         one_cache = make_cache(self.cfg, 1, len(toks))
         t0 = time.monotonic()
-        logits, one_cache = fn(self.params,
-                               batch=self._frontend_batch(toks[None, :]),
-                               cache=one_cache)
-        tok = self._sample(logits, req)
+        with span("serve.prefill", bucket=len(toks), rows=len(toks),
+                  tokens=len(toks), rids=[req.rid]):
+            logits, one_cache = fn(self.params,
+                                   batch=self._frontend_batch(toks[None, :]),
+                                   cache=one_cache)
+        tok = self._sample_slot(slot, logits, req)
         key = ("exact", len(toks))
         if self.cost is not None and key in self._timed_buckets:
             self.cost.observe_prefill(self.buckets[-1],
                                       time.monotonic() - t0)
         self._timed_buckets.add(key)
-        self.kv.insert(slot, pages, one_cache["k"][:, 0, :depth],
-                       one_cache["v"][:, 0, :depth])
+        with span("serve.insert", rid=req.rid, slot=slot, len=depth):
+            self.kv.insert(slot, pages, one_cache["k"][:, 0, :depth],
+                           one_cache["v"][:, 0, :depth])
         self._emit(req, tok)
         self.pos[slot] = depth
         self.active[slot] = req
@@ -493,19 +519,23 @@ class ServeEngine:
             lens[j] = len(toks)
         cache = make_cache(self.cfg, self.b, bkt)
         t0 = time.monotonic()
-        logits, cache = self._bucket_prefill(
-            self.params, batch=self._frontend_batch(toks_pad),
-            cache=cache, lens=jnp.asarray(lens))
-        logits = np.asarray(logits)          # sync: the wall we observe
+        with span("serve.prefill", bucket=bkt, rows=self.b * bkt,
+                  tokens=sum(len(t) for _, _, t, _ in rows),
+                  rids=[r.rid for _, r, _, _ in rows]):
+            logits, cache = self._bucket_prefill(
+                self.params, batch=self._frontend_batch(toks_pad),
+                cache=cache, lens=jnp.asarray(lens))
+        with span("serve.sync"):
+            logits = np.asarray(logits)      # sync: the wall we observe
         if self.cost is not None and bkt in self._timed_buckets:
             self.cost.observe_prefill(bkt, time.monotonic() - t0)
         self._timed_buckets.add(bkt)
         for j, (slot, req, toks, pages) in enumerate(rows):
             depth = len(toks) + self.extra
-            self.kv.insert(slot, pages, cache["k"][:, j, :depth],
-                           cache["v"][:, j, :depth])
-            self._emit(req, self._sample(jnp.asarray(logits[j:j + 1]),
-                                         req))
+            with span("serve.insert", rid=req.rid, slot=slot, len=depth):
+                self.kv.insert(slot, pages, cache["k"][:, j, :depth],
+                               cache["v"][:, j, :depth])
+            self._emit(req, self._sample_slot(slot, logits[j:j + 1], req))
             self.pos[slot] = depth
             self.active[slot] = req
         return not blocked
@@ -600,13 +630,18 @@ class ServeEngine:
         for attempt in range(self.decode_retries + 1):
             try:
                 _chaos.fire("transient_decode")
-                if self.paged:
-                    return self._decode(
-                        self.params, tokens=jnp.asarray(last),
-                        cache=self.kv.cache(), pos=pos,
-                        page_table=jnp.asarray(self.kv.table))
-                return self._decode(self.params, tokens=jnp.asarray(last),
-                                    cache=self.cache, pos=pos)
+                with span("serve.decode",
+                          active=sum(r is not None for r in self.active),
+                          pages_used=(self.alloc.total - self.alloc.available
+                                      if self.paged else 0)):
+                    if self.paged:
+                        return self._decode(
+                            self.params, tokens=jnp.asarray(last),
+                            cache=self.kv.cache(), pos=pos,
+                            page_table=jnp.asarray(self.kv.table))
+                    return self._decode(self.params,
+                                        tokens=jnp.asarray(last),
+                                        cache=self.cache, pos=pos)
             except _chaos.TransientFault:
                 self.faults["transient_retries"] += 1
                 if attempt == self.decode_retries:
@@ -644,6 +679,12 @@ class ServeEngine:
 
     def step(self) -> int:
         """One decode tick across all active slots; returns #active."""
+        with span("serve.step",
+                  active=sum(r is not None for r in self.active),
+                  queue=len(self.queue)):
+            return self._tick()
+
+    def _tick(self) -> int:
         self._expire_deadlines()
         self._admit()
         if self.paged:
@@ -660,7 +701,9 @@ class ServeEngine:
         t0 = time.monotonic()
         logits, new_cache = self._decode_with_retry(
             last, jnp.asarray(self.pos))
-        logits = _chaos.poison_logits(np.asarray(logits))
+        with span("serve.sync"):
+            logits = np.asarray(logits)
+        logits = _chaos.poison_logits(logits)
         if self.cost is not None and self._timed_step:
             self.cost.observe_step(time.monotonic() - t0)
         self._timed_step = True
@@ -684,7 +727,7 @@ class ServeEngine:
                 if r is None:       # re-prefill blocked on page pressure
                     continue
             else:
-                self._emit(r, self._sample(jnp.asarray(logits[i:i + 1]), r))
+                self._emit(r, self._sample_slot(i, logits[i:i + 1], r))
                 self.pos[i] += 1
             if (len(r.out_tokens) >= r.max_new_tokens
                     or self.pos[i] >= self.max_len - 1 + self.extra):

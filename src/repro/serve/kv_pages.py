@@ -125,9 +125,10 @@ def _scatter_rows(pool: jax.Array, rows: jax.Array,
     """Write ``rows`` (L, S, KVH, D) into the flattened-row view of
     ``pool`` (L, P, page, KVH, D) at physical row indices ``phys`` (S,)."""
     l, p, page, kvh, d = pool.shape
-    flat = pool.reshape(l, p * page, kvh, d)
-    flat = flat.at[:, phys].set(rows.astype(flat.dtype))
-    return flat.reshape(l, p, page, kvh, d)
+    with jax.named_scope("kv_insert"):
+        flat = pool.reshape(l, p * page, kvh, d)
+        flat = flat.at[:, phys].set(rows.astype(flat.dtype))
+        return flat.reshape(l, p, page, kvh, d)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

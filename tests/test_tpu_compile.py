@@ -10,6 +10,7 @@ worker imports this file.
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,15 @@ def _compile(fn, *args, **kwargs):
     return compiled
 
 
+def _assert_scopes(compiled, *scopes):
+    """The compiled program's op metadata names each of ``scopes`` as a
+    component of some op's scope path."""
+    parts = {c for name in re.findall(r'op_name="([^"]+)"',
+                                      compiled.as_text())
+             for c in name.split("/")}
+    assert set(scopes) <= parts, sorted(set(scopes) - parts)
+
+
 def _qwen(layers: int = 2):
     return dataclasses.replace(get_config("qwen3-1.7b"), num_layers=layers)
 
@@ -80,11 +90,13 @@ def test_qwen3_decode_step_paged(one_chip, pallas):
     pool = (cfg.num_layers, slots * max_pages + 1, page, cfg.num_kv_heads,
             cfg.head_dim_)
     cache = {"k": _sds(pool, BF16, one_chip), "v": _sds(pool, BF16, one_chip)}
-    _compile(functools.partial(decode_step, cfg=cfg),
-             _params(cfg, one_chip),
-             tokens=_sds((slots, 1), jnp.int32, one_chip), cache=cache,
-             pos=_sds((slots,), jnp.int32, one_chip),
-             page_table=_sds((slots, max_pages), jnp.int32, one_chip))
+    compiled = _compile(functools.partial(decode_step, cfg=cfg),
+                        _params(cfg, one_chip),
+                        tokens=_sds((slots, 1), jnp.int32, one_chip),
+                        cache=cache, pos=_sds((slots,), jnp.int32, one_chip),
+                        page_table=_sds((slots, max_pages), jnp.int32,
+                                        one_chip))
+    _assert_scopes(compiled, "attn", "mlp", "cast", "lm_head")
 
 
 def test_qwen3_prefill_bucket(one_chip, pallas):
@@ -92,10 +104,11 @@ def test_qwen3_prefill_bucket(one_chip, pallas):
     cfg = _qwen()
     b, s = 4, 128
     cache = _placed(jax.eval_shape(lambda: make_cache(cfg, b, s)), one_chip)
-    _compile(functools.partial(prefill_bucket, cfg=cfg),
-             _params(cfg, one_chip),
-             batch={"tokens": _sds((b, s), jnp.int32, one_chip)},
-             cache=cache, lens=_sds((b,), jnp.int32, one_chip))
+    compiled = _compile(functools.partial(prefill_bucket, cfg=cfg),
+                        _params(cfg, one_chip),
+                        batch={"tokens": _sds((b, s), jnp.int32, one_chip)},
+                        cache=cache, lens=_sds((b,), jnp.int32, one_chip))
+    _assert_scopes(compiled, "attn", "mlp", "cast", "lm_head")
 
 
 def test_fused_residual_down_projection(one_chip, pallas):
