@@ -47,10 +47,13 @@ DistContext is active — see models.attention.flash_decode.
 Profiler spans (``runtime.spans``; they record only under a profiler):
 ``serve.step`` (``active``, ``queue``) holds ``serve.admit`` (``free``),
 which holds each ``serve.prefill`` (``bucket``, ``rows`` = slots x bucket,
-``tokens``, ``rids``), ``serve.insert`` (``rid``, ``slot``, ``len``) and
-``serve.sample`` (``rid``, ``slot``); then ``serve.decode`` (``active``,
-``pages_used``), ``serve.sync`` (the blocking read of the logits) and one
-``serve.sample`` per active slot.
+``tokens``, ``rids``), ``serve.insert`` (``rid``, ``slot``, ``len``),
+``serve.sync`` (the blocking read of the logits) and ``serve.sample``
+(``rid``, ``slot``, ``on``); then ``serve.decode`` (``active``,
+``pages_used``), ``serve.sync`` and one ``serve.sample`` per active slot.
+Every token is chosen from the (1, V) logits row already on the host:
+greedy rows with numpy's argmax (``on="host"``), temperature rows with a
+draw on the device (``on="device"``); ``health()["sampling"]`` counts both.
 """
 from __future__ import annotations
 
@@ -173,6 +176,7 @@ class ServeEngine:
                        "nonfinite_quarantined": 0, "prefill_evictions": 0,
                        "admission_rejected": 0, "shed": 0,
                        "preemptions": 0, "bucket_misses": 0}
+        self.sampling = {"host": 0, "device": 0}
 
         self.paged = (cfg.family in PAGED_FAMILIES if paged is None
                       else paged)
@@ -317,23 +321,32 @@ class ServeEngine:
             lambda big, small: jax.lax.dynamic_update_slice_in_dim(
                 big, small.astype(big.dtype), slot, axis=1),
             self.cache, one_cache)
+        with span("serve.sync"):
+            logits = np.asarray(logits)
         self._emit(req, self._sample_slot(slot, logits, req))
         self.pos[slot] = s + self.extra
         self.active[slot] = req
 
-    def _sample_slot(self, slot: int, logits, req: Request) -> int:
-        """``_sample`` for the request in ``slot``, inside its
-        ``serve.sample`` span: the upload of its logits row (host rows
-        arrive as numpy), the sampler, and the token's read-back."""
-        with span("serve.sample", rid=req.rid, slot=slot):
-            return self._sample(jnp.asarray(logits), req)
+    def _sample_slot(self, slot: int, logits: np.ndarray,
+                     req: Request) -> int:
+        """``_sample`` for the request in ``slot`` over its (1, V) host
+        logits row, inside its ``serve.sample`` span, whose ``on`` names
+        where the token is chosen; ``self.sampling`` counts each."""
+        on = "host" if req.temperature <= 0 else "device"
+        self.sampling[on] += 1
+        with span("serve.sample", rid=req.rid, slot=slot, on=on):
+            return self._sample(logits, req)
 
-    def _sample(self, logits, req: Request) -> int:
+    def _sample(self, logits: np.ndarray, req: Request) -> int:
+        """One token from a (1, V) host logits row.  Greedy is numpy's
+        argmax, which, like ``jnp.argmax``, takes the lowest index among
+        equal maxima; a temperature draw uploads the row and splits the
+        engine's key."""
         if req.temperature <= 0:
-            return int(np.asarray(jnp.argmax(logits, -1))[0])
+            return int(np.argmax(logits[0]))
         self.key, sub = jax.random.split(self.key)
         return int(np.asarray(jax.random.categorical(
-            sub, logits / req.temperature, axis=-1))[0])
+            sub, jnp.asarray(logits) / req.temperature, axis=-1))[0])
 
     def _emit(self, req: Request, tok: int) -> None:
         req.out_tokens.append(tok)
@@ -477,6 +490,8 @@ class ServeEngine:
             logits, one_cache = fn(self.params,
                                    batch=self._frontend_batch(toks[None, :]),
                                    cache=one_cache)
+        with span("serve.sync"):
+            logits = np.asarray(logits)      # sync: the wall we observe
         tok = self._sample_slot(slot, logits, req)
         key = ("exact", len(toks))
         if self.cost is not None and key in self._timed_buckets:
@@ -649,9 +664,10 @@ class ServeEngine:
                 time.sleep(self.retry_backoff_s * (2 ** attempt))
 
     def health(self) -> dict:
-        """Operational snapshot: slot occupancy, fault counters, page-pool
-        pressure, admission pricing, and the dispatch ladder's
-        degraded-servings telemetry."""
+        """Operational snapshot: slot occupancy, fault counters, tokens
+        chosen on the host and on the device, page-pool pressure,
+        admission pricing, and the dispatch ladder's degraded-servings
+        telemetry."""
         from ..core.gemm import plan_mode_stats
         degraded = plan_mode_stats().get("degraded", {})
         out = {
@@ -660,6 +676,7 @@ class ServeEngine:
             "slot_pos": [int(p) for p in self.pos],
             "prefill_cache_size": len(self._prefill_cache),
             "faults": dict(self.faults),
+            "sampling": dict(self.sampling),
             "degraded_servings": dict(degraded),
             "degraded_mode": bool(degraded)
                              or any(self.faults.values()),

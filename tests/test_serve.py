@@ -1,9 +1,11 @@
 """Serving engine: batched continuous decoding matches single-request decode,
+tokens are chosen from the host's logits as the device would choose them,
 and the overload-safety machinery (admission, shedding, preemption, the
 bucket-miss rung, off-loop detokenization) behaves under pressure."""
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -219,3 +221,101 @@ def test_priority_protects_high_priority_from_preemption():
         eng.run([lo, hi])
     assert eng.faults["preemptions"] == 1
     assert len(lo.out_tokens) == 8 and len(hi.out_tokens) == 8
+
+
+# ------------------------------ sampling --------------------------------
+
+def _device_argmax(self, logits, req):
+    """Greedy as the engine chose it before host sampling: upload the row,
+    argmax on the device, read the index back."""
+    return int(np.asarray(jnp.argmax(jnp.asarray(logits), -1))[0])
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("tied", [(0, 4095), (9, 10, 2048), ()],
+                         ids=["first-last", "inner", "constant"])
+def test_host_greedy_breaks_ties_as_the_device(dtype, tied):
+    """Equal maxima resolve to the lowest index on the host, as
+    ``jnp.argmax`` resolves them; ``()`` is a row of one value.  The
+    greedy branch reads nothing of the engine, so no engine is built."""
+    rng = np.random.default_rng(len(tied))
+    row = (rng.standard_normal((1, 4096)) if tied
+           else np.full((1, 4096), 0.5)).astype(dtype)
+    if tied:
+        row[0, list(tied)] = row.max() + 1
+    req = Request(rid=0, prompt=np.zeros(1, np.int32))
+    host = ServeEngine._sample(None, row, req)
+    assert host == _device_argmax(None, row, req) == (tied or (0,))[0]
+
+
+@pytest.mark.parametrize("arch", ["qwen3-1.7b-smoke", "mamba2-370m-smoke"])
+def test_host_greedy_serves_the_device_greedy_tokens(arch, monkeypatch):
+    """A batched run (bucketed prefill and decode for the paged family,
+    exact-length prefill for the recurrent one) emits the same tokens as
+    one whose every token goes through the device argmax."""
+    cfg = get_config(arch)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(2, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 11, 8)]
+
+    def run():
+        eng = ServeEngine(cfg, params, batch_slots=2, max_len=32)
+        return [r.out_tokens for r in eng.run(
+            [Request(rid=i, prompt=p, max_new_tokens=6)
+             for i, p in enumerate(prompts)])]
+
+    host = run()
+    monkeypatch.setattr(ServeEngine, "_sample", _device_argmax)
+    assert run() == host
+
+
+def test_temperature_draws_follow_the_engine_key():
+    """Temperature rows draw with ``jax.random.categorical`` on the
+    engine's key, split once per such token in emission order; greedy rows
+    in the same batch split nothing."""
+    cfg, params, rng = _bits(12)
+    prompts = [rng.integers(2, cfg.vocab_size, n).astype(np.int32)
+               for n in (6, 9, 7)]
+    eng = ServeEngine(cfg, params, batch_slots=3, max_len=32, seed=41)
+    seen = []
+    sample = eng._sample
+
+    def recorded(logits, req):
+        tok = sample(logits, req)
+        seen.append((np.array(logits), req.temperature, tok))
+        return tok
+
+    eng._sample = recorded
+    # Temperatures low enough that the scaled logits, not the Gumbel
+    # noise alone, decide the draw.
+    eng.run([Request(rid=i, prompt=p, max_new_tokens=8, temperature=t)
+             for i, (p, t) in enumerate(zip(prompts, (0.1, 0.0, 0.25)))])
+    key = jax.random.PRNGKey(41)
+    drawn = off_argmax = 0
+    for row, t, tok in seen:
+        if t <= 0:
+            assert tok == int(np.argmax(row[0]))
+            continue
+        key, sub = jax.random.split(key)
+        ref = jax.random.categorical(sub, jnp.asarray(row) / t, axis=-1)
+        assert tok == int(np.asarray(ref)[0])
+        drawn += 1
+        off_argmax += tok != int(np.argmax(row[0]))
+    assert drawn == 16 and len(seen) == 24
+    assert off_argmax > 0       # the draws are not greedy in disguise
+
+
+@pytest.mark.parametrize("temperature,on", [(0.0, "host"), (0.9, "device")])
+def test_sampling_counter_counts_every_token(temperature, on):
+    cfg, params, rng = _bits(13)
+    eng = ServeEngine(cfg, params, batch_slots=2, max_len=32)
+    reqs = eng.run([Request(rid=i, prompt=rng.integers(
+        2, cfg.vocab_size, n).astype(np.int32), max_new_tokens=m,
+        temperature=temperature) for i, (n, m) in enumerate(
+            ((5, 4), (9, 7), (6, 3)))])
+    emitted = sum(len(r.out_tokens) for r in reqs)
+    assert emitted == 14
+    other = "device" if on == "host" else "host"
+    assert eng.health()["sampling"] == {on: emitted, other: 0}

@@ -74,6 +74,8 @@ def test_engine_spans_carry_their_counts(tmp_path):
     assert sorted(sampled) == sorted(
         r.rid for r in reqs for _ in r.out_tokens)
     assert all(0 <= a["slot"] < eng.b for _, _, _, a in by["serve.sample"])
+    # Every request is greedy: each token is chosen on the host.
+    assert {a["on"] for _, _, _, a in by["serve.sample"]} == {"host"}
     for _, _, _, a in by["serve.decode"]:
         assert 1 <= a["active"] <= eng.b
         assert 0 < a["pages_used"] <= eng.alloc.total
