@@ -1,8 +1,9 @@
 """The comparison that decides ``correct``.
 
 Once the window has closed, a sample of the finished requests, drawn from
-the seed, is run through the plain float32 reference
-(``reference/model.py``), one sequence at a time: the prompt followed by
+the seed, is run through the plain float32 reference of the
+configuration's model module (``forward`` of ``models/<model>.py``), one
+sequence at a time: the prompt followed by
 the served tokens.  The sample holds the longest request, then others in a
 seeded order, one from each slot before a second from any, until it holds
 the cell's ``check_tokens`` served tokens and ``check_requests`` requests.
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .reference import model as ref
+from . import spec
 
 
 def sample(recs, seed: int, min_tokens: int, min_requests: int) -> list:
@@ -62,9 +63,10 @@ def sample(recs, seed: int, min_tokens: int, min_requests: int) -> list:
     return out
 
 
-@functools.partial(jax.jit, static_argnames=("cfg_items", "control"))
-def _gaps(params, tokens, targets, valid, cfg_items, control):
-    logits, margin = ref._forward(params, tokens, cfg_items, "f32")
+@functools.partial(jax.jit, static_argnames=("model", "cfg_items",
+                                             "control"))
+def _gaps(params, tokens, targets, valid, model, cfg_items, control):
+    logits, margin = model.forward(params, tokens, cfg_items, "f32")
     best = jnp.max(logits, axis=-1)
 
     def gap_of(tok):
@@ -74,8 +76,8 @@ def _gaps(params, tokens, targets, valid, cfg_items, control):
     served = gap_of(targets)
     if not control:
         return served, served, valid, margin, served
-    low = ref._logits(params, tokens, cfg_items, "fp8")
-    same = jnp.argmax(ref._logits(params, tokens, cfg_items, "bf16"),
+    low = model.forward(params, tokens, cfg_items, "fp8")[0]
+    same = jnp.argmax(model.forward(params, tokens, cfg_items, "bf16")[0],
                       axis=-1).astype(jnp.int32)
     return (served, gap_of(jnp.argmax(low, axis=-1).astype(jnp.int32)),
             same == targets, margin, gap_of(same))
@@ -101,8 +103,9 @@ def gaps(params, cfg: dict, prompt, served, pad_to: int,
     targets[p - 1:p - 1 + n] = served
     valid = np.zeros(pad_to, bool)
     valid[p - 1:p - 1 + n] = True
+    model = spec.model_module(cfg)
     out = _gaps(params, jnp.asarray(tokens), jnp.asarray(targets),
-                jnp.asarray(valid), ref.model_keys(cfg), control)
+                jnp.asarray(valid), model, model.model_keys(cfg), control)
     return tuple(np.asarray(x)[p - 1:p - 1 + n] for x in out)
 
 

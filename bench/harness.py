@@ -6,8 +6,9 @@ driven by ``submit`` / ``step`` from the cell's traffic.
 ``measure`` makes a run and returns the result line's fields; ``run.py``
 is the command around it.  Set-up runs from the start of the process to
 the first due arrival and warms every shape the cell's traffic uses:
-the prefill bucket of each prompt length the mix can send, the page insert
-of each such length, the sampler and the decode step.
+the prefill bucket of each prompt length the mix can send, the state insert
+of each such length (the model module's ``warm_insert``: the page insert of
+the decoders), the sampler and the decode step.
 """
 from __future__ import annotations
 
@@ -77,12 +78,10 @@ class RunRecord:
     trace: object = None         # trace.Trace of the slice
 
 
-def warm_up(engine, cfg, lengths: list[int]) -> None:
+def warm_up(engine, conf: dict, lengths: list[int]) -> None:
     """Compile and load every program the window will call."""
-    from repro.models.model import make_cache
     from repro.serve.buckets import bucket_for
     from repro.serve.engine import Request
-    from repro.serve.kv_pages import pages_for
 
     by_bucket: dict[int, list[int]] = {}
     for n in lengths:
@@ -91,18 +90,10 @@ def warm_up(engine, cfg, lengths: list[int]) -> None:
     engine.run([Request(rid=-1 - i, prompt=np.full(ls[0], 2, np.int32),
                         max_new_tokens=2)
                 for i, ls in enumerate(by_bucket.values())])
-    # The page insert slices and scatters at each prompt length's shape.
-    owner = "warm-up"
+    # The state insert runs at each prompt length's shape.
+    model = spec.model_module(conf)
     for bucket, ls in by_bucket.items():
-        cache = make_cache(cfg, engine.b, bucket)
-        for n in ls:
-            pages = engine.alloc.alloc(pages_for(n + 1, engine.page_size),
-                                       owner)
-            engine.kv.insert(0, pages, cache["k"][:, 0, :n],
-                             cache["v"][:, 0, :n])
-            engine.alloc.free_owner(owner)
-            engine.kv.clear_slot(0)
-        del cache
+        model.warm_insert(engine, conf, bucket, ls)
     import jax
     jax.block_until_ready(engine.kv.k)
 
@@ -261,7 +252,7 @@ def measure(cell: spec.CellSpec, bench: dict, *, seed: int, seconds: float,
     traffic = Traffic(mix, seed, conf["vocab_size"])
     rec = Recorder(traced)
     rec.attach(engine)
-    warm_up(engine, cfg, traffic.prompt_lengths(seconds + LOAD_AFTER_S))
+    warm_up(engine, conf, traffic.prompt_lengths(seconds + LOAD_AFTER_S))
     if traffic.open_loop:
         items = traffic.arriving(seconds + LOAD_AFTER_S)
     plan_modes = plan_mode_stats()

@@ -1,2 +1,2 @@
-"""Plain float32 reference of the served models; imports nothing of the
-program under test."""
+"""Plain float32 layers of the references; imports nothing of the program
+under test."""

@@ -1,16 +1,7 @@
-"""Plain ``jax.numpy`` forward of the benchmark's decoder models, in float32
-at "highest" matmul precision: no kernels, no cache, no batching.
-
-It follows the published descriptions (Qwen3: GQA with RMSNorm on q and k
-per head, RoPE, SwiGLU MLP; Mixtral, arXiv:2401.04088: GQA, RoPE, eight
-SwiGLU experts of which the router's softmax picks two per token, gates
-renormalised over the two).  It reads the weight tree the benchmark makes
-(``bench/weights.py``) and the sizes of ``bench/configs/<config>.json``.
-Departures from the published models, the same as the served program's:
-
-* the output head is the transposed embedding (tied); Mixtral unties it;
-* RMSNorm scales are stored as offsets from one: ``x * (1 + scale)``;
-* ``rms_norm_eps`` is the configuration file's (1e-6 for both models).
+"""Plain ``jax.numpy`` layers of the benchmark's references, in float32 at
+"highest" matmul precision: no kernels, no cache, no batching.  A model
+module (``bench/models/<name>.py``) builds its forward pass from these and
+from layers of its own.
 
 ``precision="fp8"`` is the correctness control: every matmul operand is
 rounded to float8 e4m3 with a per-tensor scale before an exact product,
@@ -19,8 +10,6 @@ one precision step below the bfloat16 the configurations state.
 precision the configurations state: a witness of what rounding alone does.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -112,55 +101,3 @@ def moe(x, p, cfg: dict, precision: str):
         y = swiglu(x, p["w_gate"][e], p["w_up"][e], p["w_down"][e], precision)
         out = out + gates[:, e:e + 1] * y
     return out, margin
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "precision"))
-def _logits(params, tokens, cfg_items, precision):
-    return _forward(params, tokens, cfg_items, precision)[0]
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "precision"))
-def _forward(params, tokens, cfg_items, precision):
-    """(S, vocab) logits and (S,) the smallest router margin over the
-    layers (infinite for a dense model)."""
-    cfg = dict(cfg_items)
-    eps = cfg["rms_norm_eps"]
-    pattern = cfg["window_pattern"]
-    windows = jnp.asarray([pattern[i % len(pattern)]
-                           for i in range(cfg["num_layers"])], jnp.int32)
-
-    def layer(carry, xs):
-        h, margin = carry
-        p, window = xs
-        x = rms_norm(h, p["ln1"], eps)
-        h = h + attention(x, p["attn"], cfg, window, precision)
-        x = rms_norm(h, p["ln2"], eps)
-        if "moe" in p:
-            y, m = moe(x, p["moe"], cfg, precision)
-            return (h + y, jnp.minimum(margin, m)), None
-        return (h + swiglu(x, p["mlp"]["w_gate"], p["mlp"]["w_up"],
-                           p["mlp"]["w_down"], precision), margin), None
-
-    h = params["embed"][tokens].astype(jnp.float32)
-    margin = jnp.full(tokens.shape, jnp.inf, jnp.float32)
-    (h, margin), _ = jax.lax.scan(layer, (h, margin),
-                                  (params["layers"], windows))
-    h = rms_norm(h, params["final_norm"], eps)
-    return _mm("sd,vd->sv", h, params["embed"][:cfg["vocab_size"]],
-               precision), margin
-
-
-def model_keys(cfg: dict) -> tuple:
-    """The configuration's sizes as a hashable tuple for ``logits``."""
-    keys = ("num_layers", "num_heads", "num_kv_heads", "head_dim",
-            "vocab_size", "qk_norm", "rope_theta", "rms_norm_eps",
-            "num_experts", "top_k")
-    items = [(k, cfg.get(k, 0)) for k in keys]
-    items.append(("window_pattern", tuple(cfg.get("window_pattern", [0]))))
-    return tuple(items)
-
-
-def logits(params, cfg: dict, tokens, precision: str = "f32") -> jax.Array:
-    """(S, vocab) next-token logits at every position of ``tokens`` (S,)."""
-    return _logits(params, jnp.asarray(tokens, jnp.int32), model_keys(cfg),
-                   precision)

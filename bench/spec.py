@@ -1,12 +1,19 @@
 """Resolve the names in ``BENCHMARK.json`` to the files that hold them.
 
 Everything that belongs to one configuration, traffic mix, cell or per-layer
-metric lives in a file of its own, found by its name; adding one is adding
-files and entries, never editing the harness.
+metric lives in a file of its own, found by its name.  So does everything
+that depends on a model's layers: a configuration file's ``"model"`` key
+names its model module, ``models/<model>.py`` (``decoder`` where the key is
+absent), which holds the model's weights, plain reference, operation counts
+and state-insert warm-up (the contract is in ``models/decoder.py``).
+Adding a configuration, even one with layers of a new kind, is adding files
+and entries, never editing the harness; the program under test has to
+serve the model.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -14,6 +21,7 @@ import pathlib
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
 REPO_DIR = BENCH_DIR.parent
 BENCHMARK_JSON = REPO_DIR / "BENCHMARK.json"
+DEFAULT_MODEL = "decoder"
 
 
 def load_json(path) -> dict:
@@ -56,14 +64,34 @@ def metric_file(name: str) -> pathlib.Path:
     return BENCH_DIR / "metrics" / f"{name}.py"
 
 
-def metric_reader(name: str):
-    """The ``read(run) -> float | None`` function of one per-layer metric."""
-    path = metric_file(name)
+def _exec_file(path: pathlib.Path, prefix: str):
+    """The module of one Python file, named ``prefix`` + its stem."""
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        prefix + path.stem.replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    """The ``read(run) -> float | None`` function of one per-layer metric."""
+    return _exec_file(metric_file(name), "bench_metric_").read
+
+
+def model_file(name: str) -> pathlib.Path:
+    return BENCH_DIR / "models" / f"{name}.py"
+
+
+@functools.lru_cache(maxsize=None)
+def _model_at(path: pathlib.Path):
+    # One module object per file, so that its jitted functions, which the
+    # check takes as a static argument, compile once per process.
+    return _exec_file(path, "bench_model_")
+
+
+def model_module(conf: dict):
+    """The model module of a configuration file's dict ``conf``."""
+    return _model_at(model_file(conf.get("model", DEFAULT_MODEL)))
 
 
 def end_to_end(bench: dict, cell_name: str) -> list[dict]:

@@ -99,7 +99,7 @@ def sweep(cell, rates, schedules, seconds: float, seed: int):
     lengths = set()
     for s in schedules:     # the fastest rate reaches furthest in
         lengths |= set(traffic(s, max(rates)).prompt_lengths(seconds))
-    harness.warm_up(engine, cfg, sorted(lengths))
+    harness.warm_up(engine, conf, sorted(lengths))
     yield {"setup_s": time.perf_counter() - T_PROCESS}
     for schedule in schedules:
         for rate in rates:

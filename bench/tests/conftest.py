@@ -55,13 +55,16 @@ def measure_smoke(monkeypatch):
     monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
     before = jax.config.jax_persistent_cache_min_compile_time_secs
 
-    def run(name, *, seed=3, seconds=2.0, traced=False, limits=None):
+    def run(name, *, seed=3, seconds=2.0, traced=False, limits=None,
+            config=None):
         """One smoke run of ``name``, compared under ``limits`` (default:
-        the cell's own); the control is read too."""
+        the cell's own), its configuration updated by ``config``; the
+        control is read too."""
         from bench import harness
         bench, cs = smoke_cell(name)
         if limits is not None:
             cs.engine["limits"] = limits
+        cs.config.update(config or {})
         return harness.measure(cs, bench, seed=seed, seconds=seconds,
                                traced=traced, t_process=time.perf_counter(),
                                devices=[STAND_IN], control=True)

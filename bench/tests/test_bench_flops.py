@@ -1,8 +1,10 @@
-"""The yardstick's arithmetic, checked against counts worked out by hand."""
+"""The yardstick's arithmetic, checked against counts worked out by hand:
+the shared roofline and the decoder module's counts."""
 from bench import flops, spec
 
 QWEN = spec.load_json(spec.REPO_DIR / "bench/configs/qwen3-1.7b.json")
 MIXTRAL = spec.load_json(spec.REPO_DIR / "bench/configs/mixtral-8x7b.json")
+DECODER = spec.model_module(QWEN)
 
 
 def test_dense_token_is_two_flops_per_weight():
@@ -12,31 +14,31 @@ def test_dense_token_is_two_flops_per_weight():
     # 0.31 B tied embedding.
     assert abs(weights / 1e9 - 1.41) < 0.01
     one_key = 4 * n_l * h * hd
-    assert flops.token_flops(QWEN, 1) == 2 * weights + one_key
-    assert flops.decode_model_flops(QWEN, 0) == (
+    assert DECODER.token_flops(QWEN, 1) == 2 * weights + one_key
+    assert DECODER.decode_model_flops(QWEN, 0) == (
         2 * weights + one_key + 2 * d * 151936)
-    assert flops.prefill_model_flops(QWEN, 1) == flops.decode_model_flops(
+    assert DECODER.prefill_model_flops(QWEN, 1) == DECODER.decode_model_flops(
         QWEN, 0)
 
 
 def test_prefill_attends_causally():
     p = 100
     keys = p * (p + 1) / 2
-    dense = flops.prefill_model_flops(QWEN, p) - flops.head_flops(QWEN)
+    dense = DECODER.prefill_model_flops(QWEN, p) - DECODER.head_flops(QWEN)
     attn = 4 * 28 * 16 * 128 * keys
-    assert dense - attn == p * (flops.token_flops(QWEN, 0))
+    assert dense - attn == p * (DECODER.token_flops(QWEN, 0))
 
 
 def test_moe_token_uses_top_k_experts():
     d, f = 4096, 14336
     per_expert = 3 * d * f
-    t = flops.token_flops(MIXTRAL, 0)
+    t = DECODER.token_flops(MIXTRAL, 0)
     attn_proj = d * (32 + 16) * 128 + 32 * 128 * d
     assert t == 2 * (attn_proj + 2 * per_expert + d * 8)
 
 
 def test_decode_gemm_bytes_read_each_weight_and_key_once():
-    calls = flops.decode_gemms(QWEN, 8, 1024)
+    calls = DECODER.decode_gemms(QWEN, 8, 1024)
     by = sum(b for _, b in calls)
     weights = 28 * (2048 * 32 * 128 + 16 * 128 * 2048 + 3 * 2048 * 6144)
     weights += 2048 * 151936
@@ -46,8 +48,8 @@ def test_decode_gemm_bytes_read_each_weight_and_key_once():
 
 
 def test_routed_gemms_read_at_most_one_panel_per_row():
-    one = flops._layer_gemms(MIXTRAL, 1)
-    full = flops._layer_gemms(MIXTRAL, 32)
+    one = DECODER._layer_gemms(MIXTRAL, 1)
+    full = DECODER._layer_gemms(MIXTRAL, 32)
     panel = 3 * 4096 * 14336 * 2
     assert sum(b for _, b in one[-2:]) < 2 * panel * 1.01
     assert sum(b for _, b in full[-2:]) > 8 * panel

@@ -7,8 +7,7 @@ configuration: the dense qwen3 block and the mixtral expert block."""
 import numpy as np
 import pytest
 
-from bench import check, harness, weights
-from bench.reference import model as ref
+from bench import check, harness, spec, weights
 from conftest import smoke_cell
 
 # Program logits against the reference, max|diff| / max|reference| over a
@@ -16,6 +15,13 @@ from conftest import smoke_cell
 # rounding) with float32 accumulation land at about 1e-2.
 ROW_TOL = 4e-2
 CELLS = ["qwen3-1.7b.chat", "mixtral-8x7b.gen-batch"]
+
+
+def ref_logits(params, conf, tokens, precision="f32"):
+    """The configuration's reference: (S, vocab) logits of ``tokens``."""
+    model = spec.model_module(conf)
+    return model.forward(params, np.asarray(tokens, np.int32),
+                         model.model_keys(conf), precision)[0]
 
 
 def _serve(name):
@@ -55,7 +61,7 @@ def test_prefill_then_paged_decode_agree_with_reference(name):
     compared = 0
     for slot, r in enumerate(reqs):
         seq = np.concatenate([r.prompt, r.out_tokens[:-1]])
-        full = np.asarray(ref.logits(params, conf, seq))
+        full = np.asarray(ref_logits(params, conf, seq))
         end = len(seq)
         for s, pos, got in rows:
             if s != slot or pos >= end:
@@ -74,6 +80,6 @@ def test_fp8_control_departs_from_reference(name):
     _, cs = smoke_cell(name)
     params = weights.make_weights(cs.config, 5)
     seq = np.arange(2, 40, dtype=np.int32)
-    f32 = np.asarray(ref.logits(params, cs.config, seq))
-    low = np.asarray(ref.logits(params, cs.config, seq, "fp8"))
+    f32 = np.asarray(ref_logits(params, cs.config, seq))
+    low = np.asarray(ref_logits(params, cs.config, seq, "fp8"))
     assert np.abs(low - f32).max() > 0.05 * np.abs(f32).max()

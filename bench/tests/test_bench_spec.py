@@ -1,6 +1,6 @@
 """BENCHMARK.json keeps the benchmark contract, and every name in it
-resolves to its files; a new cell, mix, configuration or metric is found by
-adding files and entries alone."""
+resolves to its files; a new cell, mix, configuration, model module or
+metric is found by adding files and entries alone."""
 import json
 import re
 import shutil
@@ -81,6 +81,11 @@ def test_every_name_resolves_to_its_files(bench):
         conf = spec.load_json(path)
         assert conf["source"] == c["source"]
         assert set(c["reduced"]) <= set(conf)
+        model = spec.model_module(conf)
+        for name in ("tree", "model_keys", "forward", "prefill_model_flops",
+                     "decode_model_flops", "prefill_gemms", "decode_gemms",
+                     "warm_insert"):
+            assert callable(getattr(model, name)), (c["name"], name)
 
 
 def test_new_files_are_found_without_editing(bench, tmp_path, monkeypatch):
@@ -98,7 +103,8 @@ def test_new_files_are_found_without_editing(bench, tmp_path, monkeypatch):
                              "workloads": ["tiny.burst"]})
     conf = spec.load_json(spec.config_file(bench, bench["configs"][0]["name"]))
     (root / "bench/configs/tiny.json").write_text(
-        json.dumps(dict(conf, name="tiny")))
+        json.dumps(dict(conf, name="tiny", model="tiny_model")))
+    (root / "bench/models/tiny_model.py").write_text("LAYERS = 'tiny'\n")
     (root / "bench/traffic/burst.json").write_text(json.dumps(
         spec.load_json(spec.traffic_file("chat"))))
     (root / "bench/cells/tiny.burst.json").write_text(json.dumps(
@@ -112,6 +118,7 @@ def test_new_files_are_found_without_editing(bench, tmp_path, monkeypatch):
     b = spec.benchmark(root / "BENCHMARK.json")
     cs = spec.resolve(b, "tiny.burst")
     assert cs.config["name"] == "tiny" and cs.engine["slots"] == 2
+    assert spec.model_module(cs.config).LAYERS == "tiny"
     assert [m["name"] for m in spec.per_layer(b, "tiny.burst")] == [
         "compile_s", "tiny_metric"]
     assert spec.metric_reader("tiny_metric")(None) == 42.0
